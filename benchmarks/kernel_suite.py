@@ -30,11 +30,11 @@ recording what each engine's roofline-pruned search did, and
 host-side, once-per-fit work — folding it into a per-call timing would
 misprice both).
 
-Execution-mode honesty: the suite *attempts* compiled (non-interpret)
-Pallas first and falls back to interpret mode only when the platform
-refuses to lower it (CPU backends); ``REPRO_KERNEL_MODE=interpret|compiled``
-overrides the probe (DESIGN.md §7).  Every pallas row carries the live
-``interpret``/``mode`` flags, and cross-mode ratios are suppressed:
+Execution-mode honesty: the Pallas rows run compiled on a TPU and through
+the interpreter everywhere else (``ops.default_interpret``); there is no
+fallback, so a kernel the TPU compiler refuses fails the suite there
+(DESIGN.md §7).  Every pallas row carries the ``interpret``/``mode``
+flags, and cross-mode ratios are suppressed:
 ``speedup`` (vs the compiled-XLA reference) is null with
 ``comparable: false`` whenever the Pallas kernels ran interpreted.  The
 ``xla_blocked`` rows always compile — same mode as the reference on every
@@ -97,32 +97,6 @@ def _timed(fn, repeat):
     return time_call_warm(call, repeat=repeat)
 
 
-def _probe_compiled(ids, vals, means_t) -> bool:
-    """Resolve whether the Pallas rows time compiled kernels.
-
-    ``REPRO_KERNEL_MODE`` short-circuits the probe — ``compiled`` forces
-    non-interpret launches (the honest setting on TPU-class runners where
-    probing wastes a compile), ``interpret`` forces the interpreter (useful
-    for exercising the fallback path on any platform).  On ``auto`` (the
-    default) the suite *attempts* one compiled launch: True → the platform
-    lowers Pallas natively (TPU) and the whole suite times compiled
-    kernels; False → only the interpreter is available and every pallas
-    row says so (``mode: interpret``, ``comparable: false``) instead of
-    dressing interpreter dispatch up as kernel time.
-    """
-    mode = os.environ.get("REPRO_KERNEL_MODE", "auto").strip().lower()
-    if mode == "compiled":
-        return True
-    if mode == "interpret":
-        return False
-    try:
-        jax.block_until_ready(
-            ops.sparse_sim(ids[:8], vals[:8], means_t, interpret=False))
-        return True
-    except Exception:
-        return False
-
-
 def run():
     cfg = _shapes()
     b, p, d, k, repeat = cfg["b"], cfg["p"], cfg["d"], cfg["k"], cfg["repeat"]
@@ -131,8 +105,8 @@ def run():
     v_th = jnp.asarray(0.1, jnp.float32)
     shape_meta = {"B": b, "P": p, "D": d, "K": k}
 
-    compiled = _probe_compiled(ids, vals, means_t)
-    interpret = not compiled
+    interpret = ops.default_interpret()
+    compiled = not interpret
     mode = "compiled" if compiled else "interpret"
 
     # Roofline-pruned autotune at the suite's own regime, once per engine

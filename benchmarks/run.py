@@ -95,6 +95,10 @@ def main() -> None:
     args = ap.parse_args()
     only = set(args.only.split(",")) if args.only else None
 
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+
     print("name,us_per_call,derived")
     failures = 0
     for name, module in SUITES:

@@ -21,7 +21,7 @@ from repro.checkpoint.store import (load_extra, restore_checkpoint,
 from repro.cluster.classify import (classify_docs, classify_docs_routed,
                                     transform_docs)
 from repro.core.meanindex import (MeanIndex, StructuralParams,
-                                  build_mean_index)
+                                  build_mean_index, index_from_means_t)
 
 MODEL_FORMAT = "repro.cluster/fitted-model-v1"
 TWO_LEVEL_FORMAT = "repro.cluster/fitted-two-level-v1"
@@ -176,8 +176,8 @@ class FittedModel:
             TUNED_CACHE.put(tuned["signature"], TunedConfig.from_dict(tuned))
         params = StructuralParams(t_th=jnp.asarray(tree["t_th"], jnp.int32),
                                   v_th=jnp.asarray(tree["v_th"], jnp.float32))
-        index = build_mean_index(jnp.asarray(tree["means_t"]).T, params,
-                                 moving=jnp.asarray(tree["moving"]))
+        index = index_from_means_t(jnp.asarray(tree["means_t"]), params,
+                                   moving=jnp.asarray(tree["moving"]))
         return cls(index=index,
                    labels=np.asarray(tree["labels"], np.int32),
                    rho_self=np.asarray(tree["rho_self"], np.float32),
@@ -334,8 +334,8 @@ class TwoLevelFittedModel(FittedModel):
             t_th=jnp.asarray(tree["coarse_t_th"], jnp.int32),
             v_th=jnp.asarray(tree["coarse_v_th"], jnp.float32))
         return cls(
-            index=build_mean_index(jnp.asarray(tree["means_t"]).T, params,
-                                   moving=jnp.asarray(tree["moving"])),
+            index=index_from_means_t(jnp.asarray(tree["means_t"]), params,
+                                     moving=jnp.asarray(tree["moving"])),
             coarse_index=build_mean_index(
                 jnp.asarray(tree["coarse_means_t"]).T, cparams),
             cell_sizes=np.asarray(tree["cell_sizes"], np.int32),

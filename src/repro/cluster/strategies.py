@@ -44,7 +44,7 @@ import numpy as np
 
 from repro.cluster.config import ClusterConfig
 from repro.core.lloyd import LloydResult, lloyd_fit, streaming_fit
-from repro.core.meanindex import build_mean_index
+from repro.core.meanindex import index_from_means_t
 from repro.core.update import KMeansState
 from repro.sparse.store import DocStore, as_store
 
@@ -117,7 +117,9 @@ class MeshStrategy:
             checkpoint_every=config.checkpoint_every,
             tune=config.tune)
         n = docs.n_docs
-        index = build_mean_index(state.means_t.T, params, moving=state.moving)
+        # The sharded state's means are consumed by the artifact's index.
+        index = index_from_means_t(state.means_t, params,
+                                   moving=state.moving, donate=True)
         core_state = KMeansState(
             index=index,
             assign=state.assign[:n],

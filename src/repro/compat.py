@@ -1,24 +1,11 @@
-"""Version-compat bridges over moving jax APIs.
-
-The repo targets the newest TPU toolchain but must degrade gracefully on the
-older CPU-only jax found in CI images.  Everything here is a thin signature
-adapter — no behavioural changes.
-"""
+"""The repo's one ``shard_map`` policy (jax 0.9 ``jax.shard_map``)."""
 from __future__ import annotations
 
 import jax
 
-try:  # jax >= 0.5.x
-    _shard_map_new = jax.shard_map
-except AttributeError:
-    _shard_map_new = None
-    from jax.experimental.shard_map import shard_map as _shard_map_old
-
 
 def shard_map(f, *, mesh, in_specs, out_specs):
-    """``jax.shard_map`` with replication checks off, on any jax version."""
-    if _shard_map_new is not None:
-        return _shard_map_new(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_vma=False)
-    return _shard_map_old(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=False)
+    """``jax.shard_map`` with the varying-manual-axes check off: the step
+    functions mix psum'd and per-shard values the checker cannot type."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)

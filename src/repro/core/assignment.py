@@ -120,7 +120,8 @@ def _sketch_pairs(docs: SparseDocs, index: MeanIndex) -> jax.Array:
     dsk = doc_sketch(docs.ids, docs.vals, index.dim) > 0.0
     csk = index.sketch_t > 0.0
     return jnp.dot(dsk.astype(jnp.float32), csk.astype(jnp.float32),
-                   preferred_element_type=jnp.float32)
+                   preferred_element_type=jnp.float32,
+                   precision=jax.lax.Precision.HIGHEST)
 
 
 # The compound mode refines the ES bound with a Region-3 sketch check only
@@ -158,10 +159,13 @@ def _region3_bound(docs: SparseDocs, index: MeanIndex):
     r3 = jnp.where((rows[:, None] >= t_th) & (index.means_t < v_th),
                    index.means_t, 0.0)
     csk = jnp.sqrt(jax.ops.segment_sum(r3 * r3, rows // g, num_segments=s))
-    bound = jnp.dot(dsk, csk, preferred_element_type=jnp.float32)
+    # HIGHEST: a bound pruning trusts must not lose bits to bf16 passes.
+    bound = jnp.dot(dsk, csk, preferred_element_type=jnp.float32,
+                    precision=jax.lax.Precision.HIGHEST)
     pairs = jnp.dot((dsk > 0.0).astype(jnp.float32),
                     (csk > 0.0).astype(jnp.float32),
-                    preferred_element_type=jnp.float32)
+                    preferred_element_type=jnp.float32,
+                    precision=jax.lax.Precision.HIGHEST)
     return bound, pairs
 
 

@@ -364,7 +364,10 @@ class ReferenceBackend:
 
     def sketch_sim(self, docs, index, *, plan=None):
         sk = doc_sketch(docs.ids, docs.vals, index.dim)
-        return jnp.dot(sk, index.sketch_t, preferred_element_type=jnp.float32)
+        # HIGHEST: the sketch product is an upper bound pruning trusts;
+        # TPU's default f32 dot may round its operands to bf16.
+        return jnp.dot(sk, index.sketch_t, preferred_element_type=jnp.float32,
+                       precision=jax.lax.Precision.HIGHEST)
 
     def accumulate_means(self, ids, vals, assign, *, k, dim, init=None,
                          plan=None):

@@ -41,8 +41,11 @@ class EstGrid:
     chunk: int = 2048        # objects per φ̃3 chunk
 
 
+@partial(jax.jit, static_argnames=("s_min", "grid"))
 def _v_candidates(means_t: jax.Array, s_min: int, grid: EstGrid) -> jax.Array:
-    """v_th candidates from quantiles of the positive tail-region values."""
+    """v_th candidates from quantiles of the positive tail-region values.
+    One compiled program, so the tail slice and its NaN-masked copy fuse
+    into the quantile instead of each holding device memory."""
     tail = means_t[s_min:]
     masked = jnp.where(tail > 0, tail, jnp.nan)   # static shape; zeros ignored
     qs = jnp.linspace(grid.v_quantile_lo, grid.v_quantile_hi, grid.n_v)

@@ -35,7 +35,7 @@ from jax import lax
 from repro.sparse import SparseDocs, pad_rows
 from repro.core.backends import resolve_backend
 from repro.core.meanindex import (StructuralParams, build_mean_index,
-                                  normalized_means)
+                                  index_from_means_t, normalized_means)
 from repro.core.assignment import assign_batch
 from repro.core.update import (KMeansState, drift_loosen, group_drift,
                                init_state, init_state_from_store,
@@ -642,8 +642,8 @@ def _stream_ckpt_restore(directory, *, n_rows, k, dim):
     tree = {name: jnp.asarray(v) for name, v in tree.items()}
     params = StructuralParams(t_th=tree["t_th"].astype(jnp.int32),
                               v_th=tree["v_th"].astype(jnp.float32))
-    index = build_mean_index(tree["means_t"].T, params,
-                             moving=tree["moving"])
+    index = index_from_means_t(tree["means_t"], params,
+                               moving=tree["moving"])
     state = KMeansState(index=index, assign=tree["assign"],
                         rho_self=tree["rho_self"],
                         rho_self_prev=tree["rho_prev"],

@@ -137,7 +137,7 @@ class MeanIndex:
         return s_tail & (self.means_t >= self.params.v_th)
 
     def with_params(self, params: StructuralParams) -> "MeanIndex":
-        return build_mean_index(self.means_t.T, params, moving=self.moving)
+        return index_from_means_t(self.means_t, params, moving=self.moving)
 
 
 def _mf_counts(means_t: jax.Array) -> jax.Array:
@@ -170,6 +170,28 @@ def build_mean_index(means: jax.Array, params: StructuralParams,
     )
 
 
+def _index_from_means_t(means_t, params, moving):
+    return build_mean_index(means_t.T, params, moving=moving)
+
+
+_INDEX_FROM_MEANS_T = {False: jax.jit(_index_from_means_t),
+                       True: jax.jit(_index_from_means_t, donate_argnums=0)}
+
+
+def index_from_means_t(means_t: jax.Array, params: StructuralParams,
+                       moving: jax.Array | None = None, *,
+                       donate: bool = False) -> MeanIndex:
+    """:func:`build_mean_index` from the transposed (D, K) means, as one
+    compiled program: the two transposes cancel instead of each
+    materialising a copy of the index (4.95 GB per chip at nyt1m's width).
+    ``donate=True`` hands ``means_t`` over to the new index (the caller's
+    array is consumed), so no second copy of it is ever live."""
+    if moving is None:
+        moving = jnp.ones((means_t.shape[1],), bool)
+    donate = donate and jax.default_backend() != "cpu"   # CPU cannot alias
+    return _INDEX_FROM_MEANS_T[donate](means_t, params, moving)
+
+
 def normalized_means(lam: jax.Array, fallback_means_t: jax.Array) -> jax.Array:
     """(K, D) unit-norm means from cluster sums λ (Alg. 6 step 2→3).
 
@@ -199,12 +221,12 @@ def delta_v_bar(means_t: jax.Array, v_grid: jax.Array) -> jax.Array:
     Includes absent centroids (v = 0), matching the (K − mf_s)·v_h term.
     Returns (D, H) float32.
     """
-    d, k = means_t.shape
-
     def per_h(v_h):
         return jnp.mean(jnp.maximum(v_h - means_t, 0.0), axis=1)
 
-    return jax.vmap(per_h, out_axes=1)(v_grid)
+    # One candidate at a time: a vmap would materialise (H, D, K) — 55 GB
+    # at D=141,043, K=4096, H=24 — where a map needs one (D, K) temporary.
+    return jax.lax.map(per_h, v_grid).T
 
 
 def mfh_table(means_t: jax.Array, v_grid: jax.Array) -> jax.Array:
@@ -213,4 +235,4 @@ def mfh_table(means_t: jax.Array, v_grid: jax.Array) -> jax.Array:
     def per_h(v_h):
         return jnp.sum(means_t >= v_h, axis=1).astype(jnp.int32)
 
-    return jax.vmap(per_h, out_axes=1)(v_grid)
+    return jax.lax.map(per_h, v_grid).T      # one (D, K) temporary at a time

@@ -67,18 +67,29 @@ def make_corpus(spec: CorpusSpec):
     vals = np.zeros((spec.n_docs, pad), np.float32)
     nnz = np.zeros((spec.n_docs,), np.int32)
 
-    # Vectorised batched multinomial per topic for speed.
+    # One draw per topic for all of its documents: Generator.choice with
+    # ``p`` consumes one uniform per sample, in order, so this is exactly the
+    # per-document draws concatenated (same seed -> same corpus).  Each
+    # document's distinct terms and their counts come from one np.unique
+    # over (document, term) keys, ascending by term within the document.
     for t in range(spec.n_topics):
         (docs_t,) = np.nonzero(topics == t)
         if docs_t.size == 0:
             continue
-        for i in docs_t:
-            draws = rng.choice(spec.vocab, size=lengths[i], replace=True, p=topic_p[t])
-            terms, counts = np.unique(draws, return_counts=True)
-            k = min(len(terms), pad)
-            ids[i, :k] = terms[:k]
-            vals[i, :k] = counts[:k].astype(np.float32)
-            nnz[i] = k
+        lens = lengths[docs_t]
+        draws = rng.choice(spec.vocab, size=int(lens.sum()), replace=True,
+                           p=topic_p[t])
+        owner = np.repeat(np.arange(docs_t.size, dtype=np.int64), lens)
+        keys, counts = np.unique(owner * spec.vocab + draws,
+                                 return_counts=True)
+        row, term = np.divmod(keys, spec.vocab)
+        first = np.searchsorted(row, np.arange(docs_t.size))
+        slot = np.arange(keys.size) - first[row]
+        keep = slot < pad
+        ids[docs_t[row[keep]], slot[keep]] = term[keep]
+        vals[docs_t[row[keep]], slot[keep]] = counts[keep]
+        nnz[docs_t] = np.minimum(np.bincount(row, minlength=docs_t.size),
+                                 pad)
 
     docs = SparseDocs(ids=jnp.asarray(ids), vals=jnp.asarray(vals), nnz=jnp.asarray(nnz), dim=spec.vocab)
     df = df_counts(docs)

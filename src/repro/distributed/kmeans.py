@@ -416,52 +416,69 @@ def build_plan_operands(ids, vals, valid, *, dim: int, obj_chunk: int,
 # Drivers.
 # ---------------------------------------------------------------------------
 
-def dist_init_state(docs, k: int, mesh: Mesh, *, seed: int = 0) -> DistKMeansState:
+def dist_init_state(docs, k: int, mesh: Mesh, *, n_rows: int,
+                    seed: int = 0) -> DistKMeansState:
     """Seed K centroids from random documents, shard everything onto `mesh`.
 
     ``docs`` may be a resident SparseDocs or an out-of-core
     :class:`repro.sparse.DocStore` — seeding gathers only the K picked
-    rows from their chunks (the same PRNG draw and centroid construction
-    as the single-host path, so runtimes agree from iteration 0).
+    rows (the same PRNG draw and centroid construction as the single-host
+    path, so runtimes agree from iteration 0).  Nothing index- or corpus-
+    sized is built on one device: each 'model' shard scatters its own
+    K/|model| seed rows into its (D, K_loc) slice of the means, and the
+    per-document state is created directly in its sharding.  ``n_rows``
+    (>= n_docs) sizes the per-document state; rows past ``n_docs`` are
+    dead padding and start at the ρ_self = 0 / ub = 0 convention.
     """
     import numpy as np
 
-    from repro.core.meanindex import StructuralParams, build_mean_index
-    from repro.core.update import init_state, seed_centroids, seed_rows
+    from repro.core.update import n_ub_groups, seed_centroids, seed_rows
+    from repro.sparse import SparseDocs
     from repro.sparse.store import DocStore
 
     n_model = mesh.shape["model"]
     if k % n_model:
         raise ValueError(f"K={k} must divide over the model axis ({n_model})")
+    n = docs.n_docs
+    pick = np.asarray(seed_rows(n, k, seed=seed))
     if isinstance(docs, DocStore):
-        pick = seed_rows(docs.n_docs, k, seed=seed)
-        sel = docs.gather_rows(np.asarray(pick))
-        index = build_mean_index(seed_centroids(sel, k),
-                                 StructuralParams.trivial(docs.dim))
-        n = docs.n_docs
-        means_t = index.means_t
-        assign = jnp.zeros((n,), jnp.int32)
-        rho_self = jnp.full((n,), -jnp.inf, jnp.float32)
-        rho_prev = jnp.full((n,), -jnp.inf, jnp.float32)
-        from repro.core.update import n_ub_groups
-        ub = jnp.full((n, n_ub_groups(k)), jnp.inf, jnp.float32)
+        sel = docs.gather_rows(pick)
     else:
-        core = init_state(docs, k, StructuralParams.trivial(docs.dim),
-                          seed=seed)
-        means_t, assign = core.index.means_t, core.assign
-        rho_self, rho_prev = core.rho_self, core.rho_self_prev
-        ub = core.ub
+        sel = SparseDocs(ids=docs.ids[pick], vals=docs.vals[pick],
+                         nnz=docs.nnz[pick], dim=docs.dim)
+    k_loc = k // n_model
+
+    def seed_shard(ids, vals, nnz):
+        k0 = lax.axis_index("model") * k_loc
+        sl = lambda a: lax.dynamic_slice_in_dim(a, k0, k_loc, 0)
+        part = SparseDocs(ids=sl(ids), vals=sl(vals), nnz=sl(nnz),
+                          dim=docs.dim)
+        return seed_centroids(part, k_loc).T               # (D, K_loc)
+
+    means_t = jax.jit(shard_map(seed_shard, mesh=mesh,
+                                in_specs=(P(), P(), P()),
+                                out_specs=P(None, "model")))(
+        *(np.asarray(a) for a in (sel.ids, sel.vals, sel.nnz)))
+
     axes_obj = object_axes(mesh)
     sh = lambda spec: NamedSharding(mesh, spec)
-    return DistKMeansState(
-        means_t=jax.device_put(means_t, sh(P(None, "model"))),
-        assign=jax.device_put(assign, sh(P(axes_obj))),
-        rho_self=jax.device_put(rho_self, sh(P(axes_obj))),
-        rho_prev=jax.device_put(rho_prev, sh(P(axes_obj))),
-        moving=jax.device_put(jnp.ones((k,), bool), sh(P("model"))),
-        iteration=jnp.asarray(0, jnp.int32),
-        ub=jax.device_put(ub, sh(P(axes_obj, None))),
-    )
+    g = n_ub_groups(k)
+
+    def per_doc():
+        real = jnp.arange(n_rows) < n
+        rho = jnp.where(real, -jnp.inf, 0.0).astype(jnp.float32)
+        ub = jnp.where(real, jnp.inf, 0.0).astype(jnp.float32)
+        return (jnp.zeros((n_rows,), jnp.int32), rho, rho,
+                jnp.broadcast_to(ub[:, None], (n_rows, g)),
+                jnp.ones((k,), bool))
+
+    assign, rho_self, rho_prev, ub, moving = jax.jit(
+        per_doc, out_shardings=(sh(P(axes_obj)), sh(P(axes_obj)),
+                                sh(P(axes_obj)), sh(P(axes_obj, None)),
+                                sh(P("model"))))()
+    return DistKMeansState(means_t=means_t, assign=assign, rho_self=rho_self,
+                           rho_prev=rho_prev, moving=moving,
+                           iteration=jnp.asarray(0, jnp.int32), ub=ub)
 
 
 @functools.lru_cache(maxsize=None)
@@ -575,34 +592,21 @@ def mesh_fit(docs, k: int, mesh: Mesh, *, algo: str = "esicp",
         # corpus, the host only ever one chunk (+ the prefetched next).
         ids, vals, valid, pad = _place_store_sharded(store, mesh, multiple)
     else:
+        # Pad on the host and place each shard straight onto its devices:
+        # the padded corpus is never assembled on one device.
         pad = (-n) % multiple
-        ids = jnp.pad(docs.ids, ((0, pad), (0, 0)))
-        vals = jnp.pad(docs.vals, ((0, pad), (0, 0)))
-        valid = jnp.arange(n + pad) < n
-        ids = jax.device_put(ids, sh(P(axes_obj, None)))
-        vals = jax.device_put(vals, sh(P(axes_obj, None)))
-        valid = jax.device_put(valid, sh(P(axes_obj)))
+        rows = lambda a: np.pad(np.asarray(a),
+                                ((0, pad),) + ((0, 0),) * (a.ndim - 1))
+        ids = jax.device_put(rows(docs.ids), sh(P(axes_obj, None)))
+        vals = jax.device_put(rows(docs.vals), sh(P(axes_obj, None)))
+        valid = jax.device_put(np.arange(n + pad) < n, sh(P(axes_obj)))
 
-    state = dist_init_state(docs, k, mesh, seed=seed)
-    if pad:
-        # Dead tail rows carry ρ_self = 0, matching the single-host padding
-        # convention (core/lloyd.py): the refresh recomputes 0 for them every
-        # iteration (no live tuples ⇒ zero similarity) and the objective
-        # reduction masks on `valid` regardless, so the pad value never leaks
-        # into diagnostics — unlike the previous -inf sentinel, which leaked
-        # NaN-prone -inf arithmetic into any unmasked consumer.
-        state = dataclasses.replace(
-            state,
-            assign=jax.device_put(jnp.pad(state.assign, (0, pad)), sh(P(axes_obj))),
-            rho_self=jax.device_put(jnp.pad(state.rho_self, (0, pad)),
-                                    sh(P(axes_obj))),
-            rho_prev=jax.device_put(jnp.pad(state.rho_prev, (0, pad)),
-                                    sh(P(axes_obj))),
-            # Dead tail rows get ub = 0 — the ρ_self pad convention's twin
-            # (see core/update.init_state_from_store).
-            ub=jax.device_put(jnp.pad(state.ub, ((0, pad), (0, 0))),
-                              sh(P(axes_obj, None))),
-        )
+    # Dead tail rows carry ρ_self = 0 and ub = 0, matching the single-host
+    # padding convention (core/lloyd.py): the refresh recomputes 0 for them
+    # every iteration (no live tuples ⇒ zero similarity) and the objective
+    # reduction masks on `valid` regardless, so the pad value never leaks
+    # into diagnostics.
+    state = dist_init_state(docs, k, mesh, seed=seed, n_rows=n + pad)
     from repro.core.backends import resolve_backend
 
     two_phase = step_kw.pop("two_phase", False)
@@ -663,9 +667,9 @@ def mesh_fit(docs, k: int, mesh: Mesh, *, algo: str = "esicp",
                 rho_rows = state.rho_self[:n]
                 rho_rows = jnp.pad(rho_rows, (0, store.n_rows - n))
                 params, _ = estimate_params_store(
-                    store, df, state.means_t[:, :k], rho_rows, k=k)
+                    store, df, state.means_t, rho_rows, k=k)
             else:
-                params, _ = estimate_params(docs, df, state.means_t[:, :k],
+                params, _ = estimate_params(docs, df, state.means_t,
                                             state.rho_self[:n], k=k)
             if two_phase and r == max(est_iters):
                 if store is not None:

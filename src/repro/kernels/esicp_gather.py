@@ -32,7 +32,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.sparse_sim import _densify, _densify_pair, _head_index, _slab
+from repro.kernels.sparse_sim import (_COMPILER_PARAMS, _HIGHEST, _head_index,
+                                      _slab, _tile_spec, tiles_t)
 
 
 def _gather_kernel(occ_ref, scalars_ref, *refs, d_blk: int, nd: int,
@@ -66,26 +67,32 @@ def _gather_kernel(occ_ref, scalars_ref, *refs, d_blk: int, nd: int,
         means = means_ref[...]                               # (D_blk, K_sup)
         term = d0 + jax.lax.broadcasted_iota(jnp.int32, means.shape, 0)
         tail = (term.astype(jnp.float32) >= t_th)
-        hi = means >= v_th
-        exact = jnp.where(tail, hi, True)
+        # Region masks as plain compares joined by and/or: Mosaic cannot
+        # select between boolean vectors (no i8 -> i1 truncation).
+        exact = ~tail | (means >= v_th)          # Regions 1 and 2
+        region3 = tail & (means < v_th)
 
         if diag:
             slab, cslab = _slab(ids_ref, vals_ref, head_ref, headc_ref, l,
                                 d_blk=d_blk, nd=nd, n_head=n_head, diag=True)
-            w_cnt = ((means > 0) & exact).astype(jnp.float32)
+            w_cnt = jnp.where((means > 0) & exact, 1.0, 0.0)
             cnt_ref[...] += jnp.dot(cslab, w_cnt,
-                                    preferred_element_type=jnp.float32)
+                                    preferred_element_type=jnp.float32,
+                                    precision=_HIGHEST)
         else:
             slab = _slab(ids_ref, vals_ref, head_ref, headc_ref, l,
                          d_blk=d_blk, nd=nd, n_head=n_head, diag=False)
 
         rho_ref[...] += jnp.dot(slab, jnp.where(exact, means, 0.0),
-                                preferred_element_type=jnp.float32)
-        y_ref[...] += jnp.dot(slab, (tail & ~hi).astype(jnp.float32),
-                              preferred_element_type=jnp.float32)
+                                preferred_element_type=jnp.float32,
+                                precision=_HIGHEST)
+        y_ref[...] += jnp.dot(slab, jnp.where(region3, 1.0, 0.0),
+                              preferred_element_type=jnp.float32,
+                              precision=_HIGHEST)
         if with_sims:
             sims_ref[...] += jnp.dot(slab, means,
-                                     preferred_element_type=jnp.float32)
+                                     preferred_element_type=jnp.float32,
+                                     precision=_HIGHEST)
 
 
 def esicp_gather_pallas(ids, vals, means_t, t_th, v_th, occ, head=None,
@@ -103,13 +110,14 @@ def esicp_gather_pallas(ids, vals, means_t, t_th, v_th, occ, head=None,
     scalars = jnp.stack([jnp.asarray(t_th, jnp.float32),
                          jnp.asarray(v_th, jnp.float32)])
 
+    tile = lambda i, j, l, occ: i
     in_specs = [
         pl.BlockSpec((2,), lambda i, j, l, occ: (0,)),   # shared thresholds
-        pl.BlockSpec((b_blk, p), lambda i, j, l, occ: (i, 0)),
-        pl.BlockSpec((b_blk, p), lambda i, j, l, occ: (i, 0)),
+        _tile_spec(p, b_blk, tile),
+        _tile_spec(p, b_blk, tile),
         pl.BlockSpec((d_blk, k_sup), lambda i, j, l, occ: (l, j)),
     ]
-    inputs = [scalars, ids, vals, means_t]
+    inputs = [scalars, tiles_t(ids, b_blk), tiles_t(vals, b_blk), means_t]
     if n_head:
         in_specs.append(pl.BlockSpec((b_blk, d_blk), _head_index(nd, n_head)))
         inputs.append(head)
@@ -130,6 +138,7 @@ def esicp_gather_pallas(ids, vals, means_t, t_th, v_th, occ, head=None,
             num_scalar_prefetch=1, grid=grid, in_specs=in_specs,
             out_specs=out_specs),
         out_shape=out_shape,
+        compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
     )(occ, *inputs)
     return tuple(out)

@@ -56,29 +56,69 @@ def _on_tpu() -> bool:
 
 
 def default_interpret() -> bool:
-    """Resolve the interpret-mode default for a kernel launch.
-
-    ``REPRO_KERNEL_MODE`` overrides the platform policy (DESIGN.md §7):
-      auto (or unset) — compiled on TPU, interpret elsewhere;
-      interpret       — force interpret mode (semantics debugging on TPU);
-      compiled        — force compiled Pallas (off-TPU this fails at lower
-                        time unless the platform grew a Pallas lowering —
-                        the honest way to *probe* for one).
-
-    Caveat: the env var is read when a wrapper TRACES, and the jit cache
-    keys on the resolved static value — flipping the env mid-process only
-    affects shapes that have not been traced yet.  Set it before the first
-    kernel call (the bench suite reads it at startup for this reason).
-    """
-    mode = __import__("os").environ.get("REPRO_KERNEL_MODE", "auto")
-    if mode == "interpret":
-        return True
-    if mode == "compiled":
-        return False
-    if mode not in ("", "auto"):
-        raise ValueError(
-            f"REPRO_KERNEL_MODE must be auto|interpret|compiled, got {mode!r}")
+    """Interpret mode exactly when no TPU backs the process: on TPU the
+    kernels always run compiled, elsewhere through the Pallas interpreter
+    (how the CPU tests exercise them)."""
     return not _on_tpu()
+
+
+def _interpret(flag: bool | None) -> bool:
+    """Resolve a wrapper's ``interpret`` argument.  ``None`` takes the
+    platform default; ``False`` off-TPU is how a compile for a described
+    TPU is requested.  Interpret mode is refused on a TPU: nothing may put
+    the chip's kernels into the interpreter."""
+    if flag is None:
+        return default_interpret()
+    if flag and _on_tpu():
+        raise ValueError("interpret mode is not available on TPU; the "
+                         "kernels run compiled there")
+    return bool(flag)
+
+
+# The occupancy map is a scalar-prefetch operand and lives in SMEM, which
+# holds 1 MiB on v5e.  A call whose (B-tiles, D-blocks) map is larger than
+# this budget — the update phase over a whole corpus — runs as a sequence
+# of row chunks, each with its own slice of the map.
+OCC_SMEM_BYTES = 256 << 10
+
+
+def _occ_chunk_tiles(n_tiles: int, nd: int) -> int:
+    """B-tiles per launch so that one launch's occupancy map fits SMEM."""
+    return max(1, min(n_tiles, OCC_SMEM_BYTES // (4 * nd)))
+
+
+def _row_chunks(rows, occ, b_blk: int, pad_values=None):
+    """Split row operands and the occupancy map into SMEM-sized chunks.
+
+    ``rows`` is a tuple of arrays (or None) whose leading dim is the padded
+    row count.  Returns ``None`` when one launch fits, else the chunked
+    ``(rows, occ)`` with a new leading chunk axis (rows padded with
+    ``pad_values`` per operand, 0 by default; padded tiles are unoccupied).
+    """
+    nt, nd = occ.shape
+    tiles = _occ_chunk_tiles(nt, nd)
+    if tiles >= nt:
+        return None
+    nc = -(-nt // tiles)
+    occ = jnp.pad(occ, ((0, nc * tiles - nt), (0, 0))).reshape(nc, tiles, nd)
+    pad_values = pad_values or (0,) * len(rows)
+    rows = tuple(
+        None if r is None else
+        _pad_to(r, nc * tiles * b_blk, 0, value=v).reshape(
+            (nc, tiles * b_blk) + r.shape[1:])
+        for r, v in zip(rows, pad_values))
+    return rows, occ
+
+
+def _map_rows(launch, rows, occ, b_blk: int):
+    """``launch(rows, occ)`` for a row-independent kernel, run in SMEM-sized
+    row chunks when the occupancy map needs it (outputs re-joined)."""
+    chunked = _row_chunks(rows, occ, b_blk)
+    if chunked is None:
+        return launch(rows, occ)
+    out = jax.lax.map(lambda a: launch(*a), chunked)
+    return jax.tree_util.tree_map(
+        lambda o: o.reshape((-1,) + o.shape[2:]), out)
 
 
 def _resolve_cfg(tuned, plan, b_blk, k_blk, d_blk):
@@ -194,7 +234,7 @@ def sparse_sim(ids, vals, means_t, *, plan=None, tuned=None,
     ``diag=True`` additionally returns the (B, K) visited-pair counts
     (live slots × nonzero mean entries) from the same launch.
     """
-    interpret = default_interpret() if interpret is None else interpret
+    interpret = _interpret(interpret)
     cfg, b_blk, k_blk, d_blk = _resolve_cfg(tuned, plan, b_blk, k_blk, d_blk)
     b, k = ids.shape[0], means_t.shape[1]
     d = means_t.shape[0]
@@ -202,9 +242,10 @@ def sparse_sim(ids, vals, means_t, *, plan=None, tuned=None,
     ks = _pick_k_sup(pm.shape[1], k_blk, k_sup, cap=cfg.k_sup_cap)
     occ, head, headc, n_head = _plan_operands(
         plan, pi, pv, b, d, pm.shape[0], b_blk, d_blk, need_counts=diag)
-    out = _ss.sparse_sim_pallas(pi, pv, pm, occ, head, headc, b_blk=b_blk,
-                                k_sup=ks, d_blk=d_blk, n_head=n_head,
-                                diag=diag, interpret=interpret)
+    launch = lambda r, o: _ss.sparse_sim_pallas(
+        r[0], r[1], pm, o, r[2], r[3], b_blk=b_blk, k_sup=ks, d_blk=d_blk,
+        n_head=n_head, diag=diag, interpret=interpret)
+    out = _map_rows(launch, (pi, pv, head, headc), occ, b_blk)
     if diag:
         sims, counts = out
         return sims[:b, :k], counts[:b, :k]
@@ -224,7 +265,7 @@ def esicp_gather(ids, vals, means_t, t_th, v_th, *, plan=None, tuned=None,
     ``with_sims`` and by the exact-region visited-pair counts when ``diag``
     — all accumulated off one densified slab per (B, D) block.
     """
-    interpret = default_interpret() if interpret is None else interpret
+    interpret = _interpret(interpret)
     cfg, b_blk, k_blk, d_blk = _resolve_cfg(tuned, plan, b_blk, k_blk, d_blk)
     b, k = ids.shape[0], means_t.shape[1]
     d = means_t.shape[0]
@@ -232,10 +273,11 @@ def esicp_gather(ids, vals, means_t, t_th, v_th, *, plan=None, tuned=None,
     ks = _pick_k_sup(pm.shape[1], k_blk, k_sup, cap=cfg.k_sup_cap)
     occ, head, headc, n_head = _plan_operands(
         plan, pi, pv, b, d, pm.shape[0], b_blk, d_blk, need_counts=diag)
-    out = _eg.esicp_gather_pallas(pi, pv, pm, t_th, v_th, occ, head, headc,
-                                  b_blk=b_blk, k_sup=ks, d_blk=d_blk,
-                                  n_head=n_head, with_sims=with_sims,
-                                  diag=diag, interpret=interpret)
+    launch = lambda r, o: _eg.esicp_gather_pallas(
+        r[0], r[1], pm, t_th, v_th, o, r[2], r[3], b_blk=b_blk, k_sup=ks,
+        d_blk=d_blk, n_head=n_head, with_sims=with_sims, diag=diag,
+        interpret=interpret)
+    out = _map_rows(launch, (pi, pv, head, headc), occ, b_blk)
     return tuple(o[:b, :k] for o in out)
 
 
@@ -248,7 +290,7 @@ def sketch_sim(sk_docs, sketch_t, *, plan=None, tuned=None, b_blk=None,
     retained dot product bitwise equal to the unpadded reference matmul
     (kernels/ref.py sketch_sim), which the backend parity matrix relies on.
     """
-    interpret = default_interpret() if interpret is None else interpret
+    interpret = _interpret(interpret)
     cfg, b_blk, k_blk, _ = _resolve_cfg(tuned, plan, b_blk, k_blk, None)
     b, s = sk_docs.shape
     k = sketch_t.shape[1]
@@ -262,7 +304,7 @@ def sketch_sim(sk_docs, sketch_t, *, plan=None, tuned=None, b_blk=None,
 def esicp_filter(rho12, y, rho_max, col_ok, v_th, *, b_blk=128, k_blk=256,
                  interpret: bool | None = None):
     """(survivor mask int8 (B,K), |Z_i| counts (B,))."""
-    interpret = default_interpret() if interpret is None else interpret
+    interpret = _interpret(interpret)
     b, k = rho12.shape
     pr = _pad_to(_pad_to(rho12, k_blk, 1), b_blk, 0)
     py = _pad_to(_pad_to(y, k_blk, 1), b_blk, 0)
@@ -280,7 +322,7 @@ def segment_update(assign, ids, vals, *, k: int, d: int, plan=None,
                    k_sup: int | None = None,
                    interpret: bool | None = None):
     """(K, D) cluster sums λ. Padding objects get assign = k (out of range)."""
-    interpret = default_interpret() if interpret is None else interpret
+    interpret = _interpret(interpret)
     cfg, b_blk, k_blk, d_blk = _resolve_cfg(tuned, plan, b_blk, k_blk, d_blk)
     # Padded rows get assign = k: when k is block-aligned that index falls
     # past the last superblock's iota range, otherwise into a padding
@@ -294,9 +336,22 @@ def segment_update(assign, ids, vals, *, k: int, d: int, plan=None,
     ks = _pick_k_sup(kp, k_blk, k_sup, cap=cfg.k_sup_cap)
     occ, head, _, n_head = _plan_operands(
         plan, pi, pv, b, d, dp, b_blk, d_blk, need_counts=False)
-    out = _su.segment_update_pallas(pa, pi, pv, kp, dp, occ, head,
-                                    b_blk=b_blk, k_sup=ks, d_blk=d_blk,
-                                    n_head=n_head, interpret=interpret)
+    launch = partial(_su.segment_update_pallas, k=kp, d=dp, b_blk=b_blk,
+                     k_sup=ks, d_blk=d_blk, n_head=n_head,
+                     interpret=interpret)
+    chunked = _row_chunks((pa, pi, pv, head), occ, b_blk,
+                          pad_values=(k, 0, 0, 0))
+    if chunked is None:
+        out = launch(pa, pi, pv, occ=occ, head=head)
+    else:
+        # Each chunk continues the previous chunk's accumulator in place:
+        # the same sequential sum over B-tiles as one launch.
+        def body(lam, xs):
+            (ca, ci, cv, ch), co = xs
+            return launch(ca, ci, cv, occ=co, head=ch, init=lam), None
+
+        out, _ = jax.lax.scan(body, jnp.zeros((kp, dp), jnp.float32),
+                              chunked)
     return out[:k, :d]
 
 
@@ -309,7 +364,7 @@ def rho_gather(assign, ids, vals, means_t, *, plan=None, tuned=None,
 
     Padding objects get assign = k (out of range) and read back ρ = 0.
     """
-    interpret = default_interpret() if interpret is None else interpret
+    interpret = _interpret(interpret)
     cfg, b_blk, k_blk, d_blk = _resolve_cfg(tuned, plan, b_blk, k_blk, d_blk)
     b = ids.shape[0]
     k = means_t.shape[1]
@@ -319,9 +374,10 @@ def rho_gather(assign, ids, vals, means_t, *, plan=None, tuned=None,
     ks = _pick_k_sup(pm.shape[1], k_blk, k_sup, cap=cfg.k_sup_cap)
     occ, head, _, n_head = _plan_operands(
         plan, pi, pv, b, d, pm.shape[0], b_blk, d_blk, need_counts=False)
-    out = _rg.rho_gather_pallas(pa, pi, pv, pm, occ, head, b_blk=b_blk,
-                                k_sup=ks, d_blk=d_blk, n_head=n_head,
-                                interpret=interpret)
+    launch = lambda r, o: _rg.rho_gather_pallas(
+        r[0], r[1], r[2], pm, o, r[3], b_blk=b_blk, k_sup=ks, d_blk=d_blk,
+        n_head=n_head, interpret=interpret)
+    out = _map_rows(launch, (pa, pi, pv, head), occ, b_blk)
     return out[:b]
 
 
@@ -329,7 +385,7 @@ def rho_gather(assign, ids, vals, means_t, *, plan=None, tuned=None,
 def flash_attention(q, k, v, *, window: int = -1, sq_blk=128, sk_blk=128,
                     interpret: bool | None = None):
     """Banded-causal flash attention; heads folded into the batch dim."""
-    interpret = default_interpret() if interpret is None else interpret
+    interpret = _interpret(interpret)
     bh, sq, hd = q.shape
     sk = k.shape[1]
     pq = _pad_to(q, sq_blk, 1)

@@ -20,7 +20,7 @@ about a corpus (chunk) that cannot change during a fit:
   every epoch.  Caching their dense form once per chunk per fit is the TPU
   analogue of SIVF keeping the frequently-reused index region hot across
   iterations.  The cache holds the value slab *and* the live-count slab
-  (both fall out of one one-hot walk, see ``_densify_pair``) so the fused
+  (both fall out of one one-hot walk, see ``_densify``) so the fused
   Mult diagnostics reuse it too.
 
 Layout contract: plans are built against the *padded* geometry the kernel
@@ -163,11 +163,13 @@ def head_slabs(ids, vals, *, dim: int, d_blk: int = DEFAULT_D_BLK,
                with_counts: bool = True):
     """Densify the trailing ``n_head`` D-blocks once: (head, headc).
 
-    Built with the kernels' own ``_densify_pair`` walk so the cached slab is
+    Built with the kernels' own ``_densify`` walk so the cached slab is
     operation-for-operation what the kernel would have recomputed.
     """
-    from repro.kernels.sparse_sim import _densify, _densify_pair
+    from repro.kernels.sparse_sim import densify_rows
 
+    densify = jax.jit(densify_rows, static_argnums=3,
+                      static_argnames="counts")
     if n_head <= 0:
         return None, None
     rem = (-ids.shape[1]) % 8            # the wrappers' P alignment
@@ -179,10 +181,10 @@ def head_slabs(ids, vals, *, dim: int, d_blk: int = DEFAULT_D_BLK,
     for h in range(n_head):
         d0 = d_pad - (n_head - h) * d_blk
         if with_counts:
-            slab, cslab = _densify_pair(ids, vals, d0, d_blk)
+            slab, cslab = densify(ids, vals, d0, d_blk, counts=True)
             parts_c.append(cslab)
         else:
-            slab = _densify(ids, vals, d0, d_blk)
+            slab = densify(ids, vals, d0, d_blk)
         parts_v.append(slab)
     head = jnp.concatenate(parts_v, axis=1)
     return head, (jnp.concatenate(parts_c, axis=1) if with_counts else None)

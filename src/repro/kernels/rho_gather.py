@@ -31,7 +31,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.sparse_sim import _head_index, _slab
+from repro.kernels.sparse_sim import (_COMPILER_PARAMS, _HIGHEST, _head_index,
+                                      _slab, _tile_spec, tiles_t)
 
 
 def _rho_kernel(occ_ref, *refs, d_blk: int, k_sup: int, nd: int, n_head: int):
@@ -58,7 +59,8 @@ def _rho_kernel(occ_ref, *refs, d_blk: int, k_sup: int, nd: int, n_head: int):
                                         (local.shape[0], k_sup), 1)
         sel = (local[:, None] == iota).astype(jnp.float32)    # (B_blk, K_sup)
         gathered = jnp.dot(sel, means_ref[...].T,
-                           preferred_element_type=jnp.float32)  # (B_blk, D_blk)
+                           preferred_element_type=jnp.float32,
+                           precision=_HIGHEST)  # (B_blk, D_blk)
         part = jnp.sum(slab * gathered, axis=1, keepdims=True)  # (B_blk, 1)
         out_ref[...] += jnp.broadcast_to(part, (part.shape[0], 128))
 
@@ -78,13 +80,15 @@ def rho_gather_pallas(assign, ids, vals, means_t, occ, head=None, *,
     assert occ.shape == (b // b_blk, nd)
     grid = (b // b_blk, k // k_sup, nd)
 
+    tile = lambda i, j, l, occ: i
     in_specs = [
         pl.BlockSpec((b_blk, 1), lambda i, j, l, occ: (i, 0)),
-        pl.BlockSpec((b_blk, p), lambda i, j, l, occ: (i, 0)),
-        pl.BlockSpec((b_blk, p), lambda i, j, l, occ: (i, 0)),
+        _tile_spec(p, b_blk, tile),
+        _tile_spec(p, b_blk, tile),
         pl.BlockSpec((d_blk, k_sup), lambda i, j, l, occ: (l, j)),
     ]
-    inputs = [assign[:, None], ids, vals, means_t]
+    inputs = [assign[:, None], tiles_t(ids, b_blk), tiles_t(vals, b_blk),
+              means_t]
     if n_head:
         in_specs.append(pl.BlockSpec((b_blk, d_blk), _head_index(nd, n_head)))
         inputs.append(head)
@@ -97,6 +101,7 @@ def rho_gather_pallas(assign, ids, vals, means_t, occ, head=None, *,
             out_specs=pl.BlockSpec((b_blk, 128),
                                    lambda i, j, l, occ: (i, 0))),
         out_shape=jax.ShapeDtypeStruct((b, 128), jnp.float32),
+        compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
     )(occ, *inputs)
     return out[:, 0]

@@ -17,8 +17,11 @@ from jax.experimental import pallas as pl
 
 
 def _sketch_kernel(x_ref, m_ref, o_ref):
+    # HIGHEST: these sums are upper bounds that pruning trusts; a bf16
+    # rounding of either operand could push one below the exact cosine.
     o_ref[...] = jnp.dot(x_ref[...], m_ref[...],
-                         preferred_element_type=jnp.float32)
+                         preferred_element_type=jnp.float32,
+                         precision=jax.lax.Precision.HIGHEST)
 
 
 @functools.partial(jax.jit, static_argnames=("b_blk", "interpret"))

@@ -27,7 +27,7 @@ Skew-aware engine (v2):
 * **Fused Mult diagnostics.**  With ``diag`` the kernel carries a second
   accumulator ``counts[b,k] = Σ_p live[b,p]·[means[ids[b,p],k] > 0]`` —
   the paper's visited-pair count — off the same one-hot walk
-  (``_densify_pair``), so diagnostics no longer cost extra kernel launches.
+  (``_densify(counts=True)``), so diagnostics cost no extra kernel launch.
 
 The one-hot densification is the paper's inverted-index walk with the
 branch-misprediction hazard replaced by uniform lane masks — the AFM
@@ -42,72 +42,92 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+# Every f32 matmul of the clustering kernels contracts at full f32: Mosaic's
+# default may round the operands to bf16, which the exactness contract
+# (DESIGN.md §5) cannot absorb.
+_HIGHEST = jax.lax.Precision.HIGHEST
 
-def _densify(ids, vals, d0, d_blk: int, p_chunk: int = 8):
-    """(B, P) sparse tuples -> (B, D_blk) dense slab for terms [d0, d0+d_blk)."""
-    b, p = ids.shape
-    local = ids - d0
-    in_blk = (local >= 0) & (local < d_blk)
-    w = jnp.where(in_blk, vals, 0.0)
-    lid = jnp.where(in_blk, local, 0)
-    iota = jax.lax.broadcasted_iota(jnp.int32, (1, p_chunk, d_blk), 2)
-
-    def body(c, acc):
-        sl_id = jax.lax.dynamic_slice_in_dim(lid, c * p_chunk, p_chunk, 1)
-        sl_w = jax.lax.dynamic_slice_in_dim(w, c * p_chunk, p_chunk, 1)
-        onehot = (sl_id[:, :, None] == iota).astype(vals.dtype)
-        return acc + jnp.einsum("bp,bpd->bd", sl_w, onehot,
-                                preferred_element_type=jnp.float32)
-
-    acc0 = jnp.zeros((b, d_blk), jnp.float32)
-    return jax.lax.fori_loop(0, p // p_chunk, body, acc0)
+# Scoped-VMEM ceiling of the clustering kernels.  Mosaic's 16 MiB default
+# is exceeded by the fused ES gather at the widest K superblock (four
+# (b_blk, k_sup) f32 accumulators plus the (d_blk, k_sup) means block,
+# double-buffered: 16.38 MiB at k_sup=1024); every TPU from v4 on has at
+# least 32 MiB of VMEM.
+_COMPILER_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=32 << 20)
 
 
-def _densify_pair(ids, vals, d0, d_blk: int, p_chunk: int = 8):
-    """One one-hot walk, two slabs: (value slab, live-count slab).
+def tiles_t(x, b_blk: int):
+    """(B, P) row operand -> (B/b_blk, P, b_blk): each row tile with its
+    tuple slots on the sublane axis, the layout the densify walk reads."""
+    b, p = x.shape
+    return x.reshape(b // b_blk, b_blk, p).transpose(0, 2, 1)
 
-    The count slab weights every live slot (``vals != 0``) 1.0 — the operand
-    of the fused Mult accumulator.  Sharing the walk is what makes the
-    diagnostic effectively free: the onehot tensor is the expensive part.
-    """
-    b, p = ids.shape
-    local = ids - d0
-    in_blk = (local >= 0) & (local < d_blk)
-    w = jnp.where(in_blk, vals, 0.0)
-    lw = jnp.where(in_blk & (vals != 0.0), 1.0, 0.0).astype(vals.dtype)
-    lid = jnp.where(in_blk, local, 0)
-    iota = jax.lax.broadcasted_iota(jnp.int32, (1, p_chunk, d_blk), 2)
+
+def _tile_spec(p: int, b_blk: int, tile):
+    """BlockSpec of a ``tiles_t`` operand; ``tile`` maps grid indices to the
+    row tile."""
+    return pl.BlockSpec((None, p, b_blk), lambda *g: (tile(*g), 0, 0))
+
+
+def _densify(chunk, n_chunks: int, b: int, d0, d_blk: int,
+             counts: bool = False):
+    """A row tile's tuples -> its (b, D_blk) dense slab for terms
+    [d0, d0+d_blk) — with ``counts``, also the live-count slab (1.0 per live
+    slot), the operand of the fused Mult accumulator, off the same walk.
+
+    ``chunk(c)`` returns slots [8c, 8c+8) as (8, b) ids and values: slots
+    ride the sublane axis, so a chunk is an aligned dynamic row slice, which
+    Mosaic lowers, and the loop body (eight slots) does not grow with P.
+    Each chunk is transposed to (b, 8) so a slot is a column that
+    broadcasts along the slab's lanes.  No matmul touches the values (no
+    MXU rounding); term ids are distinct within a row, so each slab cell
+    receives at most one nonzero and the slab is exact whatever the order
+    of the slots."""
+    term = d0 + jax.lax.broadcasted_iota(jnp.int32, (b, d_blk), 1)
 
     def body(c, accs):
-        acc, cacc = accs
-        sl_id = jax.lax.dynamic_slice_in_dim(lid, c * p_chunk, p_chunk, 1)
-        sl_w = jax.lax.dynamic_slice_in_dim(w, c * p_chunk, p_chunk, 1)
-        sl_l = jax.lax.dynamic_slice_in_dim(lw, c * p_chunk, p_chunk, 1)
-        onehot = (sl_id[:, :, None] == iota).astype(vals.dtype)
-        return (acc + jnp.einsum("bp,bpd->bd", sl_w, onehot,
-                                 preferred_element_type=jnp.float32),
-                cacc + jnp.einsum("bp,bpd->bd", sl_l, onehot,
-                                  preferred_element_type=jnp.float32))
+        ids8, vals8 = chunk(c)
+        ids8, vals8 = ids8.T, vals8.T
+        live8 = jnp.where(vals8 != 0.0, 1.0, 0.0)
+        for s in range(8):
+            hit = ids8[:, s:s + 1] == term
+            accs = (accs[0] + jnp.where(hit, vals8[:, s:s + 1], 0.0),) + (
+                (accs[1] + jnp.where(hit, live8[:, s:s + 1], 0.0),)
+                if counts else ())
+        return accs
 
-    z = jnp.zeros((b, d_blk), jnp.float32)
-    return jax.lax.fori_loop(0, p // p_chunk, body, (z, z))
+    zero = jnp.zeros((b, d_blk), jnp.float32)
+    accs = jax.lax.fori_loop(0, n_chunks, body,
+                             (zero, zero) if counts else (zero,))
+    return accs if counts else accs[0]
+
+
+def densify_rows(ids, vals, d0, d_blk: int, counts: bool = False):
+    """The kernels' densify walk over plain (B, P) arrays (P a multiple of
+    8): how the cached head slabs are built."""
+    ids_t, vals_t = ids.T, vals.T
+    rows = lambda x, c: jax.lax.dynamic_slice_in_dim(x, c * 8, 8, 0)
+    return _densify(lambda c: (rows(ids_t, c), rows(vals_t, c)),
+                    ids.shape[1] // 8, ids.shape[0], d0, d_blk, counts)
 
 
 def _slab(ids_ref, vals_ref, head_ref, headc_ref, l, *, d_blk, nd, n_head,
           diag):
     """The (B_blk, D_blk) slab(s) for D-block ``l``: cached for the trailing
-    high-df blocks, densified otherwise."""
-    if diag:
-        build = lambda: _densify_pair(ids_ref[...], vals_ref[...],
-                                      l * d_blk, d_blk)
-        if n_head == 0:
-            return build()
-        return jax.lax.cond(l >= nd - n_head,
-                            lambda: (head_ref[...], headc_ref[...]), build)
-    build = lambda: _densify(ids_ref[...], vals_ref[...], l * d_blk, d_blk)
+    high-df blocks, densified otherwise.  ``ids_ref``/``vals_ref`` hold one
+    ``tiles_t`` tile, (P, B_blk)."""
+    p, b = ids_ref.shape
+
+    def chunk(c):
+        rows = pl.ds(pl.multiple_of(c * 8, 8), 8)
+        return ids_ref[rows, :], vals_ref[rows, :]
+
+    build = lambda: _densify(chunk, p // 8, b, l * d_blk, d_blk,
+                             counts=diag)
     if n_head == 0:
         return build()
-    return jax.lax.cond(l >= nd - n_head, lambda: head_ref[...], build)
+    cached = ((lambda: (head_ref[...], headc_ref[...])) if diag
+              else (lambda: head_ref[...]))
+    return jax.lax.cond(l >= nd - n_head, cached, build)
 
 
 def _head_index(nd: int, n_head: int):
@@ -141,12 +161,14 @@ def _sim_kernel(occ_ref, *refs, d_blk: int, nd: int, n_head: int, diag: bool):
             slab, cslab = _slab(ids_ref, vals_ref, head_ref, headc_ref, l,
                                 d_blk=d_blk, nd=nd, n_head=n_head, diag=True)
             cnt_ref[...] += jnp.dot(cslab, (means > 0).astype(jnp.float32),
-                                    preferred_element_type=jnp.float32)
+                                    preferred_element_type=jnp.float32,
+                                    precision=_HIGHEST)
         else:
             slab = _slab(ids_ref, vals_ref, head_ref, headc_ref, l,
                          d_blk=d_blk, nd=nd, n_head=n_head, diag=False)
         out_ref[...] += jnp.dot(slab, means,
-                                preferred_element_type=jnp.float32)
+                                preferred_element_type=jnp.float32,
+                                precision=_HIGHEST)
 
 
 def sparse_sim_pallas(ids, vals, means_t, occ, head=None, headc=None, *,
@@ -165,12 +187,13 @@ def sparse_sim_pallas(ids, vals, means_t, occ, head=None, headc=None, *,
     assert occ.shape == (b // b_blk, nd), (occ.shape, (b // b_blk, nd))
     grid = (b // b_blk, k // k_sup, nd)
 
+    tile = lambda i, j, l, occ: i
     in_specs = [
-        pl.BlockSpec((b_blk, p), lambda i, j, l, occ: (i, 0)),
-        pl.BlockSpec((b_blk, p), lambda i, j, l, occ: (i, 0)),
+        _tile_spec(p, b_blk, tile),
+        _tile_spec(p, b_blk, tile),
         pl.BlockSpec((d_blk, k_sup), lambda i, j, l, occ: (l, j)),
     ]
-    inputs = [ids, vals, means_t]
+    inputs = [tiles_t(ids, b_blk), tiles_t(vals, b_blk), means_t]
     if n_head:
         in_specs.append(pl.BlockSpec((b_blk, d_blk), _head_index(nd, n_head)))
         inputs.append(head)
@@ -192,6 +215,7 @@ def sparse_sim_pallas(ids, vals, means_t, occ, head=None, headc=None, *,
             num_scalar_prefetch=1, grid=grid, in_specs=in_specs,
             out_specs=out_specs),
         out_shape=out_shape,
+        compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
     )(occ, *inputs)
     return tuple(out) if diag else out[0]

@@ -56,6 +56,8 @@ import jax
 import jax.numpy as jnp
 
 f32 = jnp.float32
+# Full-f32 contraction: TPU's default f32 dot may round operands to bf16.
+_HIGHEST = jax.lax.Precision.HIGHEST
 
 # Byte budget for the gathered (B, P-chunk, K) mean-row block per fold step.
 # Shapes are static, so the chunk count is resolved at trace time; one chunk
@@ -128,7 +130,7 @@ def _mask_head(ids, vals, d0):
 
 
 def _dot(a, b):
-    return jnp.dot(a, b, preferred_element_type=f32)
+    return jnp.dot(a, b, preferred_element_type=f32, precision=_HIGHEST)
 
 
 @partial(jax.jit, static_argnames=("diag", "tuned", "b_blk", "k_blk",
@@ -146,13 +148,15 @@ def sparse_sim(ids, vals, means_t, *, plan=None, tuned=None, diag=False,
 
     def fold(acc, idp, vp, rl, rows):
         sims = acc[0] + jnp.einsum("bp,bpk->bk", vp, rows,
-                                   preferred_element_type=f32)
+                                   preferred_element_type=f32,
+                                   precision=_HIGHEST)
         if not diag:
             return (sims,)
         live = (vp != 0.0).astype(f32)
         cnt = acc[1] + jnp.einsum("bp,bpk->bk", live,
                                   (rows > 0).astype(f32),
-                                  preferred_element_type=f32)
+                                  preferred_element_type=f32,
+                                  precision=_HIGHEST)
         return (sims, cnt)
 
     init = (jnp.zeros((b, k), f32),) * (2 if diag else 1)

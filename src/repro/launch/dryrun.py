@@ -29,7 +29,8 @@ import jax
 from repro.launch.mesh import make_production_mesh
 from repro.launch.shapes import SHAPES, cell_enabled
 from repro.launch.steps import build_cell, reduced_depth_config, VARIANTS
-from repro.roofline.analysis import collective_bytes, cost_dict, roofline_terms, model_flops, HW
+from repro.roofline.analysis import (PEAKS, V5E, collective_bytes, cost_dict,
+                                     model_flops, roofline_terms)
 
 COST_KEYS = ("flops", "bytes accessed", "transcendentals")
 
@@ -129,7 +130,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, *,
             coll_raw = collective_bytes(compiled.as_text())
         # correct trip-count undercounting via the unrolled reduced-depth pass
         cx = cost_extrapolated(cfg, mesh, shape, pv)
-        terms = roofline_terms(cx["cost"], cx["collectives"])
+        terms = roofline_terms(cx["cost"], cx["collectives"], PEAKS[V5E])
         mf = model_flops(cfg, shape, n_chips)
         per_dev_bytes = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
                          + mem.output_size_in_bytes - mem.alias_size_in_bytes)
@@ -234,7 +235,7 @@ def run_kmeans_dryrun(multi_pod: bool, *, out_dir: str = RESULTS_DIR,
     try:
         mem, _, _ = compile_pass(obj_chunk, False)        # pass A: memory
         _, cost, coll = compile_pass(n // n_obj, True)    # pass B: exact cost
-        terms = roofline_terms(cost, coll)
+        terms = roofline_terms(cost, coll, PEAKS[V5E])
         per_dev_bytes = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
                          + mem.output_size_in_bytes - mem.alias_size_in_bytes)
         record.update({

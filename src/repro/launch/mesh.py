@@ -12,17 +12,11 @@ backend).
 from __future__ import annotations
 
 import jax
-
-try:  # jax >= 0.5: explicit-sharding axis types
-    from jax.sharding import AxisType as _AxisType
-except ImportError:  # older jax: meshes are implicitly Auto on every axis
-    _AxisType = None
+from jax.sharding import AxisType
 
 
 def _make_mesh(shape, axes):
-    if _AxisType is None:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(shape, axes, axis_types=(_AxisType.Auto,) * len(axes))
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -1,8 +1,12 @@
 from repro.roofline.analysis import (
     HW,
+    PEAKS,
+    V5E,
     collective_bytes,
-    roofline_terms,
     model_flops,
+    peaks,
+    roofline_terms,
 )
 
-__all__ = ["HW", "collective_bytes", "roofline_terms", "model_flops"]
+__all__ = ["HW", "PEAKS", "V5E", "collective_bytes", "model_flops", "peaks",
+           "roofline_terms"]

@@ -17,24 +17,49 @@ import re
 
 
 def cost_dict(cost) -> dict:
-    """Normalise ``compiled.cost_analysis()`` across jax versions.
-
-    Older jax returns a one-element list of dicts (per executable program);
-    newer jax returns the dict directly.  Missing/empty analyses -> {}.
-    """
-    if cost is None:
-        return {}
-    if isinstance(cost, (list, tuple)):
-        return dict(cost[0]) if cost else {}
-    return dict(cost)
+    """``compiled.cost_analysis()`` as a plain dict ({} when absent)."""
+    return dict(cost or {})
 
 
 @dataclasses.dataclass(frozen=True)
 class HW:
-    """TPU v5e-class chip."""
-    peak_flops: float = 197e12     # bf16 FLOP/s
-    hbm_bw: float = 819e9          # B/s
-    ici_bw: float = 50e9           # B/s per link
+    """Peak rates of one chip, with where they come from."""
+    peak_flops: float              # bf16 FLOP/s
+    hbm_bw: float                  # B/s
+    hbm_bytes: float               # device memory, bytes
+    ici_bw: float                  # B/s per link
+    source: str
+
+
+# The device kind JAX reports for a TPU v5e chip.
+V5E = "TPU v5 lite"
+
+# Peak table keyed by ``jax.Device.device_kind``.  A kind that is not here
+# is an error (``peaks``), never a silent default.
+PEAKS: dict[str, HW] = {
+    V5E: HW(peak_flops=197e12, hbm_bw=819e9, hbm_bytes=16e9, ici_bw=50e9,
+            source="Google Cloud, TPU v5e: 197 TFLOP/s bf16, 819 GB/s and "
+                   "16 GB HBM, 1,600 Gbit/s ICI (4 links)"),
+    # Not a CPU peak.  The autotuner's analytic pruning (tune/cost.py) runs
+    # on CPU rehearsals too; it ranks candidates with the v5e rates there,
+    # so CPU runs prune the way a chip run would.
+    "cpu": HW(peak_flops=197e12, hbm_bw=819e9, hbm_bytes=16e9, ici_bw=50e9,
+              source="v5e rates reused for CPU rehearsals of the "
+                     "autotuner's pruning; not a measured CPU peak"),
+}
+
+
+def peaks(device_kind: str | None = None) -> HW:
+    """The peak rates of ``device_kind`` (default: the first JAX device)."""
+    if device_kind is None:
+        import jax
+
+        device_kind = jax.devices()[0].device_kind
+    if device_kind not in PEAKS:
+        raise ValueError(f"no peak rates for device kind {device_kind!r}; "
+                         f"add them, with their source, to "
+                         f"repro.roofline.analysis.PEAKS")
+    return PEAKS[device_kind]
 
 
 _DTYPE_BYTES = {
@@ -84,7 +109,7 @@ def collective_bytes(hlo_text: str) -> dict:
     return out
 
 
-def roofline_terms(cost: dict, coll: dict, hw: HW = HW()) -> dict:
+def roofline_terms(cost: dict, coll: dict, hw: HW) -> dict:
     """Per-chip roofline seconds (cost_analysis is already per-device)."""
     flops = float(cost.get("flops", 0.0))
     bytes_acc = float(cost.get("bytes accessed", 0.0))

@@ -30,7 +30,7 @@ from __future__ import annotations
 
 import dataclasses
 
-from repro.roofline.analysis import HW, roofline_terms
+from repro.roofline.analysis import HW, peaks, roofline_terms
 from repro.tune.config import TunedConfig
 
 #: TPU-core VMEM budget the resident blocks must fit (bytes, conservative).
@@ -192,7 +192,7 @@ def lower_bound_seconds(cfg: TunedConfig, shape: KernelShape,
     """Roofline lower bound on the summed runtime of ``kernels`` under
     ``cfg`` — max(compute term, memory term) via roofline_terms, plus the
     per-step dispatch overhead (interpret-mode platforms)."""
-    hw = hw or HW()
+    hw = hw or peaks()
     total = 0.0
     for kernel in kernels:
         flops, nbytes, steps = kernel_flops_bytes(kernel, cfg, shape,

@@ -4,7 +4,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.roofline.analysis import collective_bytes, cost_dict, roofline_terms, HW
+from repro.roofline.analysis import (PEAKS, V5E, collective_bytes, cost_dict,
+                                     roofline_terms)
 from repro.launch.mesh import make_test_mesh
 
 
@@ -50,7 +51,7 @@ def test_cost_analysis_is_per_device():
 def test_roofline_terms_and_bottleneck():
     cost = {"flops": 197e12, "bytes accessed": 819e9 / 2}
     coll = {"total": 50e9 * 2}
-    t = roofline_terms(cost, coll, HW())
+    t = roofline_terms(cost, coll, PEAKS[V5E])
     assert abs(t["t_compute_s"] - 1.0) < 1e-9
     assert abs(t["t_memory_s"] - 0.5) < 1e-9
     assert abs(t["t_collective_s"] - 2.0) < 1e-9
@@ -83,3 +84,19 @@ def test_scan_undercount_is_corrected_by_unroll():
         set_scan_unroll(False)
         jax.clear_caches()
     assert f_unroll > 3.5 * f_loop   # 4 bodies vs 1
+
+
+def test_peaks_are_keyed_by_device_kind():
+    import pytest
+
+    from repro.roofline import peaks
+
+    v5e = peaks(V5E)
+    assert (v5e.peak_flops, v5e.hbm_bw, v5e.hbm_bytes) == (197e12, 819e9,
+                                                           16e9)
+    assert "TPU v5e" in v5e.source
+    # The CPU entry exists for the tuner's rehearsals and says so.
+    assert "not a measured CPU peak" in PEAKS["cpu"].source
+    assert peaks() is PEAKS[jax.devices()[0].device_kind]
+    with pytest.raises(ValueError, match="TPU v9"):
+        peaks("TPU v9")
