@@ -25,6 +25,7 @@ import warnings
 
 import numpy as np
 
+from repro import obs
 from repro.cluster.config import ClusterConfig
 from repro.cluster.model import FittedModel
 from repro.cluster.strategies import resolve_strategy
@@ -128,29 +129,32 @@ class SphericalKMeans:
         """Cluster ``docs`` — a resident :class:`repro.sparse.SparseDocs`
         OR an out-of-core :class:`repro.sparse.DocStore` (which routes the
         fit through the streaming strategy); returns ``self`` (sklearn
-        contract)."""
-        cfg = self.config.validate()
-        strategy = resolve_strategy(cfg, docs)
-        result = strategy.fit(docs, cfg, df=df)
-        self._fit_result = result
-        tuned = getattr(result, "tuned", None)
-        # Strategies that assemble their own artifact (two_level's nested
-        # TwoLevelFittedModel) hand it over via ``result.model``; everyone
-        # else gets the flat FittedModel built here.
-        model = getattr(result, "model", None)
-        self.model_ = model if model is not None else FittedModel(
-            index=result.state.index,
-            labels=np.asarray(result.assign, np.int32),
-            rho_self=np.asarray(result.state.rho_self, np.float32),
-            history=list(result.history),
-            converged=result.converged,
-            n_iter=result.n_iter,
-            algo=cfg.algo,
-            backend=resolve_backend(cfg.backend).name,
-            strategy=strategy.name,
-            cursor=getattr(result, "cursor", None),
-            tuned=None if tuned is None else tuned.to_dict(),
-        )
+        contract).  The whole call is the span ``repro.fit``, the parent
+        of the fit's other spans (``repro.obs``)."""
+        with obs.fit(), obs.span("fit"):
+            cfg = self.config.validate()
+            strategy = resolve_strategy(cfg, docs)
+            result = strategy.fit(docs, cfg, df=df)
+            self._fit_result = result
+            tuned = getattr(result, "tuned", None)
+            # Strategies that assemble their own artifact (two_level's
+            # nested TwoLevelFittedModel) hand it over via
+            # ``result.model``; everyone else gets the flat FittedModel
+            # built here.
+            model = getattr(result, "model", None)
+            self.model_ = model if model is not None else FittedModel(
+                index=result.state.index,
+                labels=np.asarray(result.assign, np.int32),
+                rho_self=np.asarray(result.state.rho_self, np.float32),
+                history=list(result.history),
+                converged=result.converged,
+                n_iter=result.n_iter,
+                algo=cfg.algo,
+                backend=resolve_backend(cfg.backend).name,
+                strategy=strategy.name,
+                cursor=getattr(result, "cursor", None),
+                tuned=None if tuned is None else tuned.to_dict(),
+            )
         self.labels_ = self.model_.labels
         self.history_ = self.model_.history
         self.state_ = result.state

@@ -27,6 +27,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.sparse import SparseDocs
 from repro.core.meanindex import StructuralParams, delta_v_bar, mfh_table
 
@@ -126,19 +127,23 @@ def estimate_params(docs: SparseDocs, df: jax.Array, means_t: jax.Array,
 
     rho_self: (N,) ρ_{a(i)} against the current means — the update step's
     refreshed self-similarities (Alg. 6), exactly what Alg. 7 consumes.
+    Runs in the span ``repro.estparams``, host syncs included.
     """
-    s_grid, v_grid, phi1, phi2, dvbar, colsum = _est_tables(df, means_t, grid)
+    with obs.span("estparams"):
+        s_grid, v_grid, phi1, phi2, dvbar, colsum = _est_tables(df, means_t,
+                                                                grid)
 
-    # φ̃3: chunked over objects
-    n = docs.n_docs
-    phi3 = jnp.zeros((len(s_grid), len(v_grid)))
-    for start in range(0, n, grid.chunk):
-        end = min(start + grid.chunk, n)
-        phi3 = phi3 + _phi3_chunk(docs.ids[start:end], docs.vals[start:end],
-                                  docs.nnz[start:end], dvbar, colsum,
-                                  rho_self[start:end], s_grid, k=k)
+        # φ̃3: chunked over objects
+        n = docs.n_docs
+        phi3 = jnp.zeros((len(s_grid), len(v_grid)))
+        for start in range(0, n, grid.chunk):
+            end = min(start + grid.chunk, n)
+            phi3 = phi3 + _phi3_chunk(docs.ids[start:end],
+                                      docs.vals[start:end],
+                                      docs.nnz[start:end], dvbar, colsum,
+                                      rho_self[start:end], s_grid, k=k)
 
-    return _est_minimize(s_grid, v_grid, phi1, phi2, phi3)
+        return _est_minimize(s_grid, v_grid, phi1, phi2, phi3)
 
 
 def estimate_params_store(store, df: jax.Array, means_t: jax.Array,

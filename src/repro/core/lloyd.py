@@ -32,6 +32,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from repro import obs
 from repro.sparse import SparseDocs, pad_rows
 from repro.core.backends import resolve_backend
 from repro.core.meanindex import (StructuralParams, build_mean_index,
@@ -45,6 +46,11 @@ from repro.core.estparams import estimate_params, EstGrid
 # Single host-sync points — module-level so tests can wrap them and count
 # device→host transfers.
 _host_pull = jax.device_get
+
+
+def _pull(x):
+    with obs.span("lloyd.pull"):
+        return _host_pull(x)
 
 
 def _plan_tiles(plan, nb: int, bs: int):
@@ -129,16 +135,20 @@ def _device_iteration(algo, backend, docs, state, valid, *, bs, k,
 
     Returns (state', (mult, cand_sum, n_changed, objective)).  Shared by the
     host-stepped prologue and the fused while_loop body, so both paths run
-    the identical computation graph.
+    the identical computation graph.  Its operations carry their phase in
+    their op names: ``assign``, ``update.*`` (``update_step``) and
+    ``lloyd.diag``.
     """
     prev_assign = state.assign
-    assign, ub, mult, cand_sum, n_changed = _fused_epoch(
-        algo, backend, docs, state.index, state.assign, state.rho_self,
-        state.xstate, valid, bs, plan, state.ub)
+    with jax.named_scope("assign"):
+        assign, ub, mult, cand_sum, n_changed = _fused_epoch(
+            algo, backend, docs, state.index, state.assign, state.rho_self,
+            state.xstate, valid, bs, plan, state.ub)
     state = update_step(docs, assign, prev_assign, state,
                         state.index.params, k=k, backend=backend,
                         plan=_update_plan(plan, bs), ub=ub)
-    objective = jnp.sum(jnp.where(valid, state.rho_self, 0.0))
+    with jax.named_scope("lloyd.diag"):
+        objective = jnp.sum(jnp.where(valid, state.rho_self, 0.0))
     return state, (mult, cand_sum, n_changed, objective)
 
 
@@ -166,15 +176,16 @@ def _fused_fit_body(state, docs, valid, last_changed, plan, *, algo, backend,
         state, (mult, cand, changed, obj) = _device_iteration(
             algo, backend, docs, state, valid, bs=bs, k=k, plan=plan)
         changed = changed.astype(jnp.int32)
-        ring = {
-            "mult": ring["mult"].at[it].set(mult),
-            "cand": ring["cand"].at[it].set(cand.astype(jnp.float32)),
-            "changed": ring["changed"].at[it].set(changed),
-            "objective": ring["objective"].at[it].set(obj),
-            "n_moving": ring["n_moving"].at[it].set(state.index.n_moving),
-            "t_th": ring["t_th"].at[it].set(state.index.params.t_th),
-            "v_th": ring["v_th"].at[it].set(state.index.params.v_th),
-        }
+        with jax.named_scope("lloyd.diag"):
+            ring = {
+                "mult": ring["mult"].at[it].set(mult),
+                "cand": ring["cand"].at[it].set(cand.astype(jnp.float32)),
+                "changed": ring["changed"].at[it].set(changed),
+                "objective": ring["objective"].at[it].set(obj),
+                "n_moving": ring["n_moving"].at[it].set(state.index.n_moving),
+                "t_th": ring["t_th"].at[it].set(state.index.params.t_th),
+                "v_th": ring["v_th"].at[it].set(state.index.params.v_th),
+            }
         return (state, it + 1, changed, ring)
 
     state, n_steps, _, ring = lax.while_loop(
@@ -186,11 +197,16 @@ def _fused_fit_body(state, docs, valid, last_changed, plan, *, algo, backend,
 @functools.lru_cache(maxsize=None)
 def _fused_fit_fn(algo: str, backend: str, bs: int, k: int, max_steps: int):
     """Jitted fused-fit entry, donated state buffers (donation is a no-op on
-    CPU, where XLA has no aliasing support — skipped to avoid the warning)."""
+    CPU, where XLA has no aliasing support — skipped to avoid the warning).
+    Its executable is named ``jit_lloyd_fused_fit``."""
     donate = (0,) if jax.default_backend() != "cpu" else ()
-    return jax.jit(partial(_fused_fit_body, algo=algo, backend=backend,
-                           bs=bs, k=k, max_steps=max_steps),
-                   donate_argnums=donate)
+
+    def lloyd_fused_fit(state, docs, valid, last_changed, plan):
+        return _fused_fit_body(state, docs, valid, last_changed, plan,
+                               algo=algo, backend=backend, bs=bs, k=k,
+                               max_steps=max_steps)
+
+    return jax.jit(lloyd_fused_fit, donate_argnums=donate)
 
 
 def _run_fused(algo, backend, bs, k, max_steps, state, docs, valid,
@@ -235,7 +251,8 @@ def initial_params(spec, dim: int) -> StructuralParams:
 
 
 def _history_row(r: int, n: int, k: int, mult, cand, changed, obj, nmov,
-                 t_th, v_th, elapsed: float) -> dict:
+                 t_th, v_th, elapsed: float, counts: dict | None = None
+                 ) -> dict:
     return {
         "iteration": r,
         "mult": float(mult),
@@ -246,7 +263,33 @@ def _history_row(r: int, n: int, k: int, mult, cand, changed, obj, nmov,
         "elapsed_s": elapsed,
         "t_th": int(t_th),
         "v_th": float(v_th),
+        **(counts or {}),
     }
+
+
+class _RowCounts:
+    """Each history row's share of the open fit's counters (``repro.obs``):
+    what happened since the previous row was taken, so that a fit's rows
+    sum to what happened inside it.  ``compiles``, ``compile_s`` and
+    ``cache_hits`` are XLA compilations, their seconds and persistent-cache
+    hits; ``estparams_s`` is the host time of the ``repro.estparams``
+    span."""
+
+    def __init__(self, rec: obs.FitRecord):
+        self._rec = rec
+        self._mark = self._now()
+
+    def _now(self) -> dict:
+        rec = self._rec
+        return {"compiles": rec.compiles, "compile_s": rec.compile_s,
+                "cache_hits": rec.cache_hits,
+                "estparams_s": rec.span_s.get("estparams", 0.0)}
+
+    def take(self) -> dict:
+        now = self._now()
+        share = {key: now[key] - self._mark[key] for key in now}
+        self._mark = now
+        return share
 
 
 def lloyd_fit(docs: SparseDocs, *, k: int, algo: str = "esicp",
@@ -272,115 +315,146 @@ def lloyd_fit(docs: SparseDocs, *, k: int, algo: str = "esicp",
     This is the ``single_host`` execution strategy behind the
     :class:`repro.cluster.SphericalKMeans` estimator; call the estimator for
     the artifact-producing front door, this for the raw :class:`LloydResult`.
+
+    Traced in ``repro.lloyd.*`` spans (``repro.obs``): ``prepare``, one
+    ``iteration`` per prologue iteration, ``fused``, ``pull`` around every
+    device→host sync and ``finish``.  Each history row also holds its share
+    of the fit's counters (:class:`_RowCounts`): the first row holds the
+    set-up's, the first fused row the whole fused call's (0 on the later
+    ones) and the last row the finish's.
     """
-    est_grid = est_grid or EstGrid()
-    est_iters = tuple(est_iters)
-    n = docs.n_docs
-    init_params = initial_params(params, docs.dim)
-    # Seeding picks centroids among the *real* documents, before padding.
-    state = init_state(docs, k, init_params, seed=seed)
-    if df is None:
-        df = docs.df            # cached on the corpus (sparse/matrix.py)
+    with obs.fit() as rec:
+        counts = _RowCounts(rec)
+        est_grid = est_grid or EstGrid()
+        est_iters = tuple(est_iters)
+        n = docs.n_docs
+        with obs.span("lloyd.prepare"):
+            init_params = initial_params(params, docs.dim)
+            # Seeding picks centroids among the *real* documents, before
+            # padding.
+            state = init_state(docs, k, init_params, seed=seed)
+            if df is None:
+                df = docs.df        # cached on the corpus (sparse/matrix.py)
 
-    bs = min(batch_size, n)
-    pdocs = pad_rows(docs, bs)
-    n_pad = pdocs.n_docs
-    valid = jnp.arange(n_pad) < n
-    # Epoch-invariant kernel plan (occupancy + cached high-df head slabs):
-    # documents never change across Lloyd iterations, so the pallas
-    # backend densifies the head region and maps the live cells exactly
-    # once per fit; the reference backend has nothing to cache (None).
-    plan = resolve_backend(backend).prepare(pdocs, tile_rows=bs, k=k,
-                                            tune=tune,
-                                            tune_budget=tune_budget)
-    if n_pad != n:
-        pad = n_pad - n
-        # Dead rows carry ρ_self = 0 — exactly the value every update
-        # step recomputes for them (no live tuples ⇒ zero similarity) —
-        # and the objective reduction masks on `valid` regardless, so
-        # padding never leaks into the history.
-        state = dataclasses.replace(
-            state,
-            assign=jnp.pad(state.assign, (0, pad)),
-            rho_self=jnp.pad(state.rho_self, (0, pad)),
-            rho_self_prev=jnp.pad(state.rho_self_prev, (0, pad)),
-            # Dead rows pad ub = 0 (the ρ_self convention's twin): their
-            # bound may drift upward across updates, which is harmless —
-            # dead rows have no live tuples, so they contribute zero Mult
-            # and are valid-masked out of |Z| / #changed.
-            ub=jnp.pad(state.ub, ((0, pad), (0, 0))),
-        )
+            bs = min(batch_size, n)
+            pdocs = pad_rows(docs, bs)
+            n_pad = pdocs.n_docs
+            valid = jnp.arange(n_pad) < n
+            # Epoch-invariant kernel plan (occupancy + cached high-df head
+            # slabs): documents never change across Lloyd iterations, so the
+            # pallas backend densifies the head region and maps the live
+            # cells exactly once per fit; the reference backend has nothing
+            # to cache (None).
+            plan = resolve_backend(backend).prepare(pdocs, tile_rows=bs, k=k,
+                                                    tune=tune,
+                                                    tune_budget=tune_budget)
+            if n_pad != n:
+                pad = n_pad - n
+                # Dead rows carry ρ_self = 0 — exactly the value every update
+                # step recomputes for them (no live tuples ⇒ zero similarity)
+                # — and the objective reduction masks on `valid` regardless,
+                # so padding never leaks into the history.
+                state = dataclasses.replace(
+                    state,
+                    assign=jnp.pad(state.assign, (0, pad)),
+                    rho_self=jnp.pad(state.rho_self, (0, pad)),
+                    rho_self_prev=jnp.pad(state.rho_self_prev, (0, pad)),
+                    # Dead rows pad ub = 0 (the ρ_self convention's twin):
+                    # their bound may drift upward across updates, which is
+                    # harmless — dead rows have no live tuples, so they
+                    # contribute zero Mult and are valid-masked out of |Z| /
+                    # #changed.
+                    ub=jnp.pad(state.ub, ((0, pad), (0, 0))),
+                )
 
-    history = []
-    converged = False
+        history = []
+        converged = False
 
-    # --- Prologue: the EstParams iterations, host-stepped -------------
-    # estimate_params needs host-side grid bookkeeping (dynamic-shape
-    # candidate grids), so iterations 1..max(est_iters) run outside the
-    # fused loop: still fully on device per step, with one diagnostic
-    # pull each — a constant ≤ max(est_iters) syncs.
-    prologue = 0
-    if params == "auto" and est_iters:
-        prologue = min(max(est_iters), max_iter)
-    for r in range(1, prologue + 1):
-        t0 = time.perf_counter()
-        state, (mult, cand_sum, n_changed, _) = _device_iteration(
-            algo, backend, pdocs, state, valid, bs=bs, k=k, plan=plan)
-        if r in est_iters:
-            # EstParams sees only the real rows (padding would skew the
-            # Mult-estimate tables).
-            new_params, _ = estimate_params(docs, df, state.index.means_t,
-                                            state.rho_self[:n], k=k,
-                                            grid=est_grid)
-            state = dataclasses.replace(
-                state, index=state.index.with_params(new_params))
-        diag = _host_pull(
-            (mult, cand_sum, n_changed,
-             jnp.sum(jnp.where(valid, state.rho_self, 0.0)),
-             state.index.n_moving, state.index.params.t_th,
-             state.index.params.v_th))
-        history.append(_history_row(
-            r, n, k, *diag, time.perf_counter() - t0))
-        if history[-1]["n_changed"] == 0:
-            converged = True
-            break
+        # --- Prologue: the EstParams iterations, host-stepped ---------
+        # estimate_params needs host-side grid bookkeeping (dynamic-shape
+        # candidate grids), so iterations 1..max(est_iters) run outside the
+        # fused loop: still fully on device per step, with one diagnostic
+        # pull each — a constant ≤ max(est_iters) syncs.
+        prologue = 0
+        if params == "auto" and est_iters:
+            prologue = min(max(est_iters), max_iter)
+        for r in range(1, prologue + 1):
+            with obs.span("lloyd.iteration", iteration=r):
+                t0 = time.perf_counter()
+                state, (mult, cand_sum, n_changed, _) = _device_iteration(
+                    algo, backend, pdocs, state, valid, bs=bs, k=k,
+                    plan=plan)
+                if r in est_iters:
+                    # EstParams' first host sync waits for this iteration
+                    # on the device; waiting here instead keeps that wait
+                    # out of its span.
+                    jax.block_until_ready(state)
+                    # EstParams sees only the real rows (padding would skew
+                    # the Mult-estimate tables).
+                    new_params, _ = estimate_params(
+                        docs, df, state.index.means_t, state.rho_self[:n],
+                        k=k, grid=est_grid)
+                    state = dataclasses.replace(
+                        state, index=state.index.with_params(new_params))
+                diag = _pull(
+                    (mult, cand_sum, n_changed,
+                     jnp.sum(jnp.where(valid, state.rho_self, 0.0)),
+                     state.index.n_moving, state.index.params.t_th,
+                     state.index.params.v_th))
+                history.append(_history_row(
+                    r, n, k, *diag, time.perf_counter() - t0, counts.take()))
+            if history[-1]["n_changed"] == 0:
+                converged = True
+                break
 
-    # --- Fused remainder: one jitted call, O(1) host syncs ------------
-    max_steps = max_iter - len(history)
-    if not converged and max_steps > 0:
-        last_changed = jnp.asarray(
-            history[-1]["n_changed"] if history else 1, jnp.int32)
-        t0 = time.perf_counter()
-        state, n_steps, ring = _run_fused(
-            algo, backend, bs, k, max_steps,
-            state, pdocs, valid, last_changed, plan)
-        # The one device→host sync of the fused remainder: the executed
-        # step count and every diagnostic ring cross in a single pull.
-        steps, ring_h = _host_pull((n_steps, ring))
-        steps = int(steps)
-        per_iter = (time.perf_counter() - t0) / max(steps, 1)
-        for i in range(steps):
-            history.append(_history_row(
-                len(history) + 1, n, k, ring_h["mult"][i], ring_h["cand"][i],
-                ring_h["changed"][i], ring_h["objective"][i],
-                ring_h["n_moving"][i], ring_h["t_th"][i],
-                ring_h["v_th"][i], per_iter))
-        converged = steps > 0 and int(ring_h["changed"][steps - 1]) == 0
+        # --- Fused remainder: one jitted call, O(1) host syncs --------
+        max_steps = max_iter - len(history)
+        if not converged and max_steps > 0:
+            last_changed = jnp.asarray(
+                history[-1]["n_changed"] if history else 1, jnp.int32)
+            with obs.span("lloyd.fused"):
+                t0 = time.perf_counter()
+                state, n_steps, ring = _run_fused(
+                    algo, backend, bs, k, max_steps,
+                    state, pdocs, valid, last_changed, plan)
+                # The one device→host sync of the fused remainder: the
+                # executed step count and every diagnostic ring cross in a
+                # single pull.
+                steps, ring_h = _pull((n_steps, ring))
+                steps = int(steps)
+                per_iter = (time.perf_counter() - t0) / max(steps, 1)
+            fused_counts = counts.take()
+            for i in range(steps):
+                history.append(_history_row(
+                    len(history) + 1, n, k, ring_h["mult"][i],
+                    ring_h["cand"][i], ring_h["changed"][i],
+                    ring_h["objective"][i], ring_h["n_moving"][i],
+                    ring_h["t_th"][i], ring_h["v_th"][i], per_iter,
+                    fused_counts if i == 0
+                    else dict.fromkeys(fused_counts, 0)))
+            converged = steps > 0 and int(ring_h["changed"][steps - 1]) == 0
 
-    if n_pad != n:
-        # Trim the padding rows so state arrays pair with the caller's
-        # docs again (dead rows carry ρ_self = 0, so Σ ρ_self — the
-        # objective — is identical before and after the trim).
-        state = dataclasses.replace(
-            state,
-            assign=state.assign[:n],
-            rho_self=state.rho_self[:n],
-            rho_self_prev=state.rho_self_prev[:n],
-            ub=state.ub[:n],
-        )
+        with obs.span("lloyd.finish"):
+            if n_pad != n:
+                # Trim the padding rows so state arrays pair with the
+                # caller's docs again (dead rows carry ρ_self = 0, so
+                # Σ ρ_self — the objective — is identical before and after
+                # the trim).
+                state = dataclasses.replace(
+                    state,
+                    assign=state.assign[:n],
+                    rho_self=state.rho_self[:n],
+                    rho_self_prev=state.rho_self_prev[:n],
+                    ub=state.ub[:n],
+                )
+            assign = np.asarray(state.assign)
+        if history:
+            last = history[-1]
+            for key, value in counts.take().items():
+                last[key] += value
     return LloydResult(
         state=state,
-        assign=np.asarray(state.assign),
+        assign=assign,
         history=history,
         params=state.index.params,
         converged=converged,

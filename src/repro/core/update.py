@@ -158,26 +158,37 @@ def update_step(docs: SparseDocs, assign: jax.Array, prev_assign: jax.Array,
     modes); None keeps the previous state's.  Either way the stored bound
     is loosened by the max per-center angular drift of THIS update, so it
     remains a true upper bound against the new means.
+
+    Its operations carry their phase in their op names: ``update.sums``,
+    ``update.normalize``, ``update.index``, ``update.rho`` and
+    ``update.bounds``.
     """
     from repro.core.backends import resolve_backend
 
     bk = resolve_backend(backend)
-    vals = jnp.where(docs.row_mask(), docs.vals, 0.0)
-    lam = bk.accumulate_means(docs.ids, vals, assign, k=k, dim=docs.dim,
-                              plan=plan)
-    means = normalized_means(lam, prev_state.index.means_t)
-    index = build_mean_index(means, params,
-                             moving=moving_flags(assign, prev_assign, k))
-    rho_self = bk.self_sims(docs.ids, vals, assign, index.means_t, plan=plan)
-    ub = prev_state.ub if ub is None else ub
-    delta = group_drift(index.means_t, prev_state.index.means_t)
+    with jax.named_scope("update.sums"):
+        vals = jnp.where(docs.row_mask(), docs.vals, 0.0)
+        lam = bk.accumulate_means(docs.ids, vals, assign, k=k, dim=docs.dim,
+                                  plan=plan)
+    with jax.named_scope("update.normalize"):
+        means = normalized_means(lam, prev_state.index.means_t)
+    with jax.named_scope("update.index"):
+        index = build_mean_index(means, params,
+                                 moving=moving_flags(assign, prev_assign, k))
+    with jax.named_scope("update.rho"):
+        rho_self = bk.self_sims(docs.ids, vals, assign, index.means_t,
+                                plan=plan)
+    with jax.named_scope("update.bounds"):
+        ub = prev_state.ub if ub is None else ub
+        delta = group_drift(index.means_t, prev_state.index.means_t)
+        ub = drift_loosen(ub, delta)
     return KMeansState(
         index=index,
         assign=assign,
         rho_self=rho_self,
         rho_self_prev=prev_state.rho_self,
         iteration=prev_state.iteration + 1,
-        ub=drift_loosen(ub, delta),
+        ub=ub,
     )
 
 
