@@ -3,7 +3,7 @@
 The algorithms here are pure selection logic: they consume accumulators
 (exact similarities, region-wise partial sums, survivor masks) produced by a
 pluggable :class:`repro.core.backends.Backend` — ``reference`` (the TAAT
-``lax.scan``, the paper's MIVI loop order and this repo's exactness oracle)
+slot loop, the paper's MIVI loop order and this repo's exactness oracle)
 or ``pallas`` (the TPU kernels in :mod:`repro.kernels.ops`, interpret mode
 off-TPU).  See backends.py / DESIGN.md §5 for the split.
 

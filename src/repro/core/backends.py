@@ -7,10 +7,10 @@ reductions (cluster sums, ρ_self refresh).  This module owns *how* both
 phases' accumulators are produced:
 
 ``reference``
-    Assignment: the TAAT ``lax.scan`` over padded object tuples.  Update:
-    the dense ``at[].add`` scatter and the own-centroid gather.  Runs
-    everywhere, no alignment constraints, and is the exactness oracle every
-    other backend is tested against.
+    Assignment: the TAAT slot loop over padded object tuples, run to each
+    tile's live width.  Update: the dense ``at[].add`` scatter and the
+    own-centroid gather.  Runs everywhere, no alignment constraints, and is
+    the exactness oracle every other backend is tested against.
 
 ``pallas``
     Assignment: the TPU Pallas kernels in :mod:`repro.kernels.ops`
@@ -155,7 +155,7 @@ class Backend(Protocol):
 
 
 # ---------------------------------------------------------------------------
-# Reference backend: the TAAT lax.scan (moved verbatim from assignment.py).
+# Reference backend: the TAAT slot loop.
 # ---------------------------------------------------------------------------
 
 def _pad_p(ids, vals, pb: int):
@@ -190,7 +190,13 @@ def reference_scan(docs: SparseDocs, index: MeanIndex, xstate, *, mode: str,
                     them before touching the (B, K) accumulators: accumulator
                     read/write traffic drops ~p_block× at unchanged gather
                     traffic;
-      unroll      — unroll the scan (dry-run exact-FLOPs costing).
+      unroll      — a static scan over all P slots, unrolled (dry-run
+                    exact-FLOPs costing).
+
+    Without ``unroll`` the loop runs only to the tile's live width,
+    ``max(nnz)`` rounded up to a ``p_block`` multiple: every slot past it
+    is dead in every row, and a dead slot adds exact zeros to every carry.
+    The result is bit-identical to the scan over all P slots.
     """
     b, p = docs.ids.shape
     k = index.k
@@ -208,12 +214,12 @@ def reference_scan(docs: SparseDocs, index: MeanIndex, xstate, *, mode: str,
         contrib = vp[..., None] * rows
         sims = carry["sims"] + jnp.sum(contrib, 0)
         out = {"sims": sims, "mult": carry["mult"]}
+        live = (vp != 0.0)[..., None]         # (pb, B, 1)
         if diag:
-            live = vp != 0.0
-            nz = (rows > 0) & col_ok[None] & live[..., None]
+            nz = (rows > 0) & col_ok[None] & live
             # Raw visited pairs (no ICP mask) — the per-(B, K) twin the
             # Pallas diag accumulator produces; ``mult`` keeps col_ok.
-            nzr = (rows > 0) & live[..., None]
+            nzr = (rows > 0) & live
         if mode == "exact":
             if diag:
                 out["mult"] = carry["mult"] + jnp.sum(nz, dtype=f32)
@@ -249,7 +255,7 @@ def reference_scan(docs: SparseDocs, index: MeanIndex, xstate, *, mode: str,
             out["rho1"] = carry["rho1"] + jnp.sum(
                 jnp.where(tail, 0.0, contrib), 0)
             out["sq"] = carry["sq"] + jnp.sum(
-                jnp.where(tail, rows * rows, 0.0), 0)
+                jnp.where(tail & live, rows * rows, 0.0), 0)
             if diag:
                 out["mult"] = carry["mult"] + jnp.sum(nz, dtype=f32)
         else:
@@ -270,8 +276,19 @@ def reference_scan(docs: SparseDocs, index: MeanIndex, xstate, *, mode: str,
                                                              docs.vals, pb)
     pp = ids.shape[1]
     xs = (ids.T.reshape(pp // pb, pb, b), vals.T.reshape(pp // pb, pb, b))
-    out, _ = jax.lax.scan(body, carry, xs, unroll=unroll)
-    return out
+    if unroll:
+        out, _ = jax.lax.scan(body, carry, xs, unroll=unroll)
+        return out
+    return jax.lax.fori_loop(
+        0, live_steps(docs.nnz, pp, pb),
+        lambda s, c: body(c, (xs[0][s], xs[1][s]))[0], carry)
+
+
+def live_steps(nnz: jax.Array, p: int, pb: int = 1) -> jax.Array:
+    """Slot steps of :func:`reference_scan` over a tile: its longest row's
+    ``nnz`` (at most ``p``) in blocks of ``pb`` slots, rounded up."""
+    w = jnp.minimum(jnp.max(nnz, initial=0), p)
+    return (w + pb - 1) // pb
 
 
 def gather_verify_scan(ids, vals, nnz, means_t, t_th, v_th, rho_max, col_ok,
@@ -474,9 +491,9 @@ class PallasBackend:
                 head_vals = jnp.where(docs.ids < t_th, docs.vals, 0.0)
                 out["rho1"] = ops.sparse_sim(docs.ids, head_vals, means_t,
                                              tuned=tuned)
-                # Σ over slots of means², including the reference scan's
-                # dead-slot quirk (padding ids are 0, counted iff t_th == 0).
-                tail_ones = (docs.ids >= t_th).astype(jnp.float32)
+                # Σ over live tail slots of means², as the reference scan.
+                tail_ones = ((docs.ids >= t_th)
+                             & (docs.vals != 0.0)).astype(jnp.float32)
                 out["sq"] = ops.sparse_sim(docs.ids, tail_ones,
                                            means_t * means_t, tuned=tuned)
         elif mode == "esicp":

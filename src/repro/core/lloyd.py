@@ -19,6 +19,8 @@ Each assignment epoch is a ``lax.map`` over reshaped batches: documents are
 padded to a batch-size multiple with dead rows (nnz = 0, ρ_self = 0) that
 are masked out of every diagnostic; the tail batch therefore runs through
 the identical code path as full batches (tested in tests/test_backends.py).
+On the reference engine the batches are tiles of documents of like length
+(:class:`TileOrder`), each scanned only to its longest document.
 """
 from __future__ import annotations
 
@@ -26,6 +28,7 @@ import dataclasses
 import functools
 import time
 from functools import partial
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -34,7 +37,7 @@ from jax import lax
 
 from repro import obs
 from repro.sparse import SparseDocs, pad_rows
-from repro.core.backends import resolve_backend
+from repro.core.backends import live_steps, resolve_backend
 from repro.core.meanindex import (StructuralParams, build_mean_index,
                                   index_from_means_t, normalized_means)
 from repro.core.assignment import assign_batch
@@ -81,10 +84,45 @@ def _update_plan(plan, bs: int):
     return plan if bs % plan.b_blk == 0 else plan.without_occ()
 
 
+class TileOrder(NamedTuple):
+    """The padded corpus with its rows ordered by length, for the epoch's
+    tiles: each tile then holds documents of like length, and the
+    reference scan runs each only to its longest document.  Documents are
+    constant across iterations, so it is built once per fit."""
+
+    docs: SparseDocs     # rows in length order
+    perm: jax.Array      # (N,) int32: ordered row -> original row
+    inv: jax.Array       # (N,) int32: original row -> ordered row
+
+
+@jax.jit
+def length_order(docs: SparseDocs) -> TileOrder:
+    """Rows by ``nnz``, stably: rows of equal length keep their order."""
+    n = docs.n_docs
+    perm = jnp.argsort(docs.nnz, stable=True).astype(jnp.int32)
+    inv = jnp.zeros((n,), jnp.int32).at[perm].set(
+        jnp.arange(n, dtype=jnp.int32))
+    return TileOrder(docs=SparseDocs(ids=docs.ids[perm],
+                                     vals=docs.vals[perm],
+                                     nnz=docs.nnz[perm], dim=docs.dim),
+                     perm=perm, inv=inv)
+
+
+@partial(jax.jit, static_argnames=("p", "bs"))
+def scan_slot_share(nnz: jax.Array, p: int, bs: int) -> jax.Array:
+    """Slot steps of one assignment epoch over ``bs``-row tiles of rows of
+    these ``nnz`` (in the order the epoch scans them), as a share of the
+    tiles × ``p`` steps of a scan over every slot."""
+    nb = nnz.shape[0] // bs
+    steps = jnp.sum(jax.vmap(lambda t: live_steps(t, p))(
+        nnz.reshape(nb, bs)))
+    return steps / (nb * p)
+
+
 @partial(jax.jit, static_argnames=("algo", "backend", "bs"))
 def _fused_epoch(algo: str, backend: str, docs: SparseDocs, index,
                  assign, rho_self, xstate, valid, bs: int, plan=None,
-                 ub=None):
+                 ub=None, order: TileOrder | None = None):
     """One full assignment epoch over a resident slab, on device.
 
     A chunk-scan: ``lax.scan`` over ``bs``-row tiles whose *carry* is the
@@ -100,11 +138,21 @@ def _fused_epoch(algo: str, backend: str, docs: SparseDocs, index,
     ``tile_rows=bs`` (``Backend.prepare``); its occupancy/head-slab arrays
     ride the scan as per-tile xs beside the data tiles.  ``ub`` is the
     maintained per-object bound (bounds modes; None → +inf 'unknown').
+
+    ``order`` (:class:`TileOrder` of ``docs``) makes the tiles those of its
+    length-ordered rows: the per-document inputs are taken through its
+    ``perm`` and the results put back through its ``inv``, so the outputs
+    stay in ``docs``' row order.  Each document's own sums are unchanged;
+    only the float ``mult`` is added up over the tiles in another order.
     """
     n = docs.ids.shape[0]
     nb = n // bs
     if ub is None:
         ub = jnp.full((n, n_ub_groups(index.k)), jnp.inf, jnp.float32)
+    if order is not None:
+        docs = order.docs
+        assign, rho_self, xstate, valid, ub = (
+            a[order.perm] for a in (assign, rho_self, xstate, valid, ub))
     resh = lambda a: a.reshape((nb, bs) + a.shape[1:])
 
     def tile_fn(carry, xs):
@@ -126,11 +174,14 @@ def _fused_epoch(algo: str, backend: str, docs: SparseDocs, index,
           resh(assign), resh(rho_self), resh(xstate), resh(valid),
           resh(ub)),
          _plan_tiles(plan, nb, bs)))
-    return a.reshape(n), u.reshape((n,) + u.shape[2:]), mult, cand, changed
+    a, u = a.reshape(n), u.reshape((n,) + u.shape[2:])
+    if order is not None:
+        a, u = a[order.inv], u[order.inv]
+    return a, u, mult, cand, changed
 
 
 def _device_iteration(algo, backend, docs, state, valid, *, bs, k,
-                      plan=None):
+                      plan=None, order=None):
     """One full Lloyd iteration (epoch + update), traceable on device.
 
     Returns (state', (mult, cand_sum, n_changed, objective)).  Shared by the
@@ -143,7 +194,7 @@ def _device_iteration(algo, backend, docs, state, valid, *, bs, k,
     with jax.named_scope("assign"):
         assign, ub, mult, cand_sum, n_changed = _fused_epoch(
             algo, backend, docs, state.index, state.assign, state.rho_self,
-            state.xstate, valid, bs, plan, state.ub)
+            state.xstate, valid, bs, plan, state.ub, order)
     state = update_step(docs, assign, prev_assign, state,
                         state.index.params, k=k, backend=backend,
                         plan=_update_plan(plan, bs), ub=ub)
@@ -152,8 +203,8 @@ def _device_iteration(algo, backend, docs, state, valid, *, bs, k,
     return state, (mult, cand_sum, n_changed, objective)
 
 
-def _fused_fit_body(state, docs, valid, last_changed, plan, *, algo, backend,
-                    bs, k, max_steps):
+def _fused_fit_body(state, docs, valid, last_changed, plan, order=None, *,
+                    algo, backend, bs, k, max_steps):
     """The fused remainder of the fit: a ``lax.while_loop`` over iterations.
 
     Carries (state, step counter, #changed of the previous iteration, ring
@@ -174,7 +225,8 @@ def _fused_fit_body(state, docs, valid, last_changed, plan, *, algo, backend,
     def body(carry):
         state, it, _, ring = carry
         state, (mult, cand, changed, obj) = _device_iteration(
-            algo, backend, docs, state, valid, bs=bs, k=k, plan=plan)
+            algo, backend, docs, state, valid, bs=bs, k=k, plan=plan,
+            order=order)
         changed = changed.astype(jnp.int32)
         with jax.named_scope("lloyd.diag"):
             ring = {
@@ -201,8 +253,8 @@ def _fused_fit_fn(algo: str, backend: str, bs: int, k: int, max_steps: int):
     Its executable is named ``jit_lloyd_fused_fit``."""
     donate = (0,) if jax.default_backend() != "cpu" else ()
 
-    def lloyd_fused_fit(state, docs, valid, last_changed, plan):
-        return _fused_fit_body(state, docs, valid, last_changed, plan,
+    def lloyd_fused_fit(state, docs, valid, last_changed, plan, order=None):
+        return _fused_fit_body(state, docs, valid, last_changed, plan, order,
                                algo=algo, backend=backend, bs=bs, k=k,
                                max_steps=max_steps)
 
@@ -210,10 +262,10 @@ def _fused_fit_fn(algo: str, backend: str, bs: int, k: int, max_steps: int):
 
 
 def _run_fused(algo, backend, bs, k, max_steps, state, docs, valid,
-               last_changed, plan=None):
+               last_changed, plan=None, order=None):
     """Indirection point for tests asserting the fused path is one call."""
     fn = _fused_fit_fn(algo, backend, bs, k, max_steps)
-    return fn(state, docs, valid, last_changed, plan)
+    return fn(state, docs, valid, last_changed, plan, order)
 
 
 @dataclasses.dataclass
@@ -321,7 +373,10 @@ def lloyd_fit(docs: SparseDocs, *, k: int, algo: str = "esicp",
     device→host sync and ``finish``.  Each history row also holds its share
     of the fit's counters (:class:`_RowCounts`): the first row holds the
     set-up's, the first fused row the whole fused call's (0 on the later
-    ones) and the last row the finish's.
+    ones) and the last row the finish's.  ``scan_slot_share`` is the same
+    on every row: :func:`scan_slot_share` of the epoch's tiles (engines
+    with a plan run no slot scan; there it reads what the reference scan
+    would take over their tiles).
     """
     with obs.fit() as rec:
         counts = _RowCounts(rec)
@@ -348,6 +403,14 @@ def lloyd_fit(docs: SparseDocs, *, k: int, algo: str = "esicp",
             plan = resolve_backend(backend).prepare(pdocs, tile_rows=bs, k=k,
                                                     tune=tune,
                                                     tune_budget=tune_budget)
+            # The reference engine (no plan) scans each tile only to its
+            # longest document, so its tiles take documents of like length.
+            # A plan is laid out in the corpus's row order and is handed to
+            # the update as well, so engines with one keep that order.
+            order = length_order(pdocs) if plan is None else None
+            slot_share = scan_slot_share(
+                (pdocs if order is None else order.docs).nnz,
+                p=pdocs.pad_width, bs=bs)
             if n_pad != n:
                 pad = n_pad - n
                 # Dead rows carry ρ_self = 0 — exactly the value every update
@@ -383,7 +446,7 @@ def lloyd_fit(docs: SparseDocs, *, k: int, algo: str = "esicp",
                 t0 = time.perf_counter()
                 state, (mult, cand_sum, n_changed, _) = _device_iteration(
                     algo, backend, pdocs, state, valid, bs=bs, k=k,
-                    plan=plan)
+                    plan=plan, order=order)
                 if r in est_iters:
                     # EstParams' first host sync waits for this iteration
                     # on the device; waiting here instead keeps that wait
@@ -400,9 +463,10 @@ def lloyd_fit(docs: SparseDocs, *, k: int, algo: str = "esicp",
                     (mult, cand_sum, n_changed,
                      jnp.sum(jnp.where(valid, state.rho_self, 0.0)),
                      state.index.n_moving, state.index.params.t_th,
-                     state.index.params.v_th))
+                     state.index.params.v_th, slot_share))
                 history.append(_history_row(
-                    r, n, k, *diag, time.perf_counter() - t0, counts.take()))
+                    r, n, k, *diag[:-1], time.perf_counter() - t0,
+                    {"scan_slot_share": float(diag[-1]), **counts.take()}))
             if history[-1]["n_changed"] == 0:
                 converged = True
                 break
@@ -416,11 +480,11 @@ def lloyd_fit(docs: SparseDocs, *, k: int, algo: str = "esicp",
                 t0 = time.perf_counter()
                 state, n_steps, ring = _run_fused(
                     algo, backend, bs, k, max_steps,
-                    state, pdocs, valid, last_changed, plan)
+                    state, pdocs, valid, last_changed, plan, order)
                 # The one device→host sync of the fused remainder: the
                 # executed step count and every diagnostic ring cross in a
                 # single pull.
-                steps, ring_h = _pull((n_steps, ring))
+                steps, ring_h, share = _pull((n_steps, ring, slot_share))
                 steps = int(steps)
                 per_iter = (time.perf_counter() - t0) / max(steps, 1)
             fused_counts = counts.take()
@@ -430,8 +494,9 @@ def lloyd_fit(docs: SparseDocs, *, k: int, algo: str = "esicp",
                     ring_h["cand"][i], ring_h["changed"][i],
                     ring_h["objective"][i], ring_h["n_moving"][i],
                     ring_h["t_th"][i], ring_h["v_th"][i], per_iter,
-                    fused_counts if i == 0
-                    else dict.fromkeys(fused_counts, 0)))
+                    {"scan_slot_share": float(share),
+                     **(fused_counts if i == 0
+                        else dict.fromkeys(fused_counts, 0))}))
             converged = steps > 0 and int(ring_h["changed"][steps - 1]) == 0
 
         with obs.span("lloyd.finish"):

@@ -68,35 +68,33 @@ ROWS_BUDGET = 32 << 20
 def _chunks(ids, vals, k: int):
     """Split the posting axis into gather-budget chunks.
 
-    Returns ``(nc, xs)`` where each of ``xs = (ids, vals, real)`` is shaped
-    (nc, B, pc).  ``real`` marks caller-provided slots: chunk padding must
-    stay invisible even to accumulators with the reference scan's dead-slot
-    semantics (CS counts ``id >= t_th`` slots whether live or not)."""
+    Returns ``(nc, xs)`` where each of ``xs = (ids, vals)`` is shaped
+    (nc, B, pc).  Chunk padding is dead (id 0, val 0), like the caller's
+    own padding."""
     b, p = ids.shape
     pc = int(max(1, min(p, ROWS_BUDGET // max(1, b * k * 4))))
     rem = (-p) % pc
-    real = jnp.broadcast_to(jnp.arange(p + rem)[None, :] < p, (b, p + rem))
     if rem:
         ids = jnp.pad(ids, ((0, 0), (0, rem)))
         vals = jnp.pad(vals, ((0, 0), (0, rem)))
     nc = (p + rem) // pc
     resh = lambda a: a.reshape(b, nc, pc).transpose(1, 0, 2)
-    return nc, (resh(ids), resh(vals), resh(real))
+    return nc, (resh(ids), resh(vals))
 
 
 def _gather_fold(ids, vals, means_t, fold, init):
-    """Fold ``fold(acc, idp, vp, real, rows)`` over P-chunks of the postings,
+    """Fold ``fold(acc, idp, vp, rows)`` over P-chunks of the postings,
     gathering ``rows = means_t[idp]`` per chunk.  Single-chunk calls skip
     the scan entirely (one gather + one fold in straight-line HLO)."""
-    nc, (cids, cvals, creal) = _chunks(ids, vals, means_t.shape[1])
+    nc, (cids, cvals) = _chunks(ids, vals, means_t.shape[1])
     if nc == 1:
-        return fold(init, cids[0], cvals[0], creal[0], means_t[cids[0]])
+        return fold(init, cids[0], cvals[0], means_t[cids[0]])
 
     def body(acc, xs):
-        idp, vp, rl = xs
-        return fold(acc, idp, vp, rl, means_t[idp]), None
+        idp, vp = xs
+        return fold(acc, idp, vp, means_t[idp]), None
 
-    acc, _ = jax.lax.scan(body, init, (cids, cvals, creal))
+    acc, _ = jax.lax.scan(body, init, (cids, cvals))
     return acc
 
 
@@ -146,7 +144,7 @@ def sparse_sim(ids, vals, means_t, *, plan=None, tuned=None, diag=False,
                                            need_counts=diag)
     tvals = _mask_head(ids, vals, d0)
 
-    def fold(acc, idp, vp, rl, rows):
+    def fold(acc, idp, vp, rows):
         sims = acc[0] + jnp.einsum("bp,bpk->bk", vp, rows,
                                    preferred_element_type=f32,
                                    precision=_HIGHEST)
@@ -198,7 +196,7 @@ def esicp_gather(ids, vals, means_t, t_th, v_th, *, v_ta=None, plan=None,
     tvals = _mask_head(ids, vals, d0)
     thr = v_ta[:, None, None] if per_object else v_th
 
-    def fold(acc, idp, vp, rl, rows):
+    def fold(acc, idp, vp, rows):
         tail = (idp >= t_th)[..., None]
         hi = rows >= thr
         exact = jnp.where(tail, hi, True)
@@ -251,21 +249,21 @@ def cs_gather(ids, vals, means_t, t_th, *, plan=None, tuned=None, diag=False,
     """CS partials (sims, rho1, sq[, counts]) in ONE fused pass — the Pallas
     backend needs three ``sparse_sim`` launches for the same accumulators.
 
-    No head split: ``sq`` follows the reference scan's per-*slot* semantics
-    (every slot with ``id >= t_th`` contributes means², live or not — the
-    dead-slot quirk), which the live-count slab cannot express; precedent is
+    No head split: ``sq`` sums means² over the live slots with
+    ``id >= t_th``, which the live-count slab cannot express; precedent is
     the Pallas backend bypassing its head cache for CS too."""
     b = ids.shape[0]
     k = means_t.shape[1]
 
-    def fold(acc, idp, vp, rl, rows):
-        tail = ((idp >= t_th) & rl)[..., None]   # rl: chunk padding is unreal
+    def fold(acc, idp, vp, rows):
+        tail = (idp >= t_th)[..., None]
+        live = (vp != 0.0)[..., None]
         contrib = vp[..., None] * rows
         out = {"sims": acc["sims"] + jnp.sum(contrib, 1),
                "rho1": acc["rho1"] + jnp.sum(jnp.where(tail, 0.0, contrib), 1),
-               "sq": acc["sq"] + jnp.sum(jnp.where(tail, rows * rows, 0.0), 1)}
+               "sq": acc["sq"] + jnp.sum(jnp.where(tail & live, rows * rows,
+                                                   0.0), 1)}
         if diag:
-            live = (vp != 0.0)[..., None]
             out["counts"] = acc["counts"] + jnp.sum(
                 (rows > 0) & live, 1, dtype=f32)
         return out

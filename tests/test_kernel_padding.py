@@ -194,9 +194,9 @@ def test_xla_esicp_gather_per_object_threshold(case):
 @given(ragged_case())
 def test_xla_cs_gather_any_shape(case):
     """The fused CS op vs slot-semantics oracles: rho1 drops tail-slot
-    contributions, sq sums means² over every slot with id >= t_th — live
-    or dead (the reference scan's dead-slot quirk, which the op's internal
-    chunk padding must NOT add to)."""
+    contributions, sq sums means² over the live slots with id >= t_th —
+    dead slots, the caller's or the op's internal chunk padding, add
+    nothing (the reference scan's semantics)."""
     ids, vals, means_t, assign, t_th, v_th, plan = case
     sims, rho1, sq, counts = xb.cs_gather(ids, vals, means_t, t_th,
                                           plan=plan, diag=True)
@@ -208,7 +208,8 @@ def test_xla_cs_gather_any_shape(case):
         np.asarray(rho1),
         np.asarray(ref.sparse_sim(ids, head_vals, means_t)),
         rtol=1e-4, atol=1e-4)
-    tail01 = (np.asarray(ids) >= t_th).astype(np.float32)  # per SLOT, not live
+    tail01 = ((np.asarray(ids) >= t_th)
+              & (np.asarray(vals) != 0)).astype(np.float32)  # live slots
     np.testing.assert_allclose(
         np.asarray(sq),
         np.asarray(ref.sparse_sim(ids, jnp.asarray(tail01), means_t ** 2)),
