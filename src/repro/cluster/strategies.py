@@ -42,6 +42,7 @@ from typing import Protocol
 
 import numpy as np
 
+from repro import obs
 from repro.cluster.config import ClusterConfig
 from repro.core.lloyd import LloydResult, lloyd_fit, streaming_fit
 from repro.core.meanindex import index_from_means_t
@@ -99,6 +100,8 @@ class MeshStrategy:
     detail: the strategy trims padding and repackages the final shard state
     as an ordinary :class:`KMeansState`, so everything downstream — the
     FittedModel artifact, predict/classify, save/load — is runtime-blind.
+    That repackaging is the span ``repro.mesh.finish``; its counters go to
+    the last history row, as ``lloyd_fit``'s finish does.
     """
 
     name = "mesh"
@@ -108,29 +111,37 @@ class MeshStrategy:
 
         if config.mesh is None:
             raise ValueError("MeshStrategy needs ClusterConfig(mesh=...)")
-        state, history, converged, params = mesh_fit(
-            docs, config.k, config.mesh, algo=config.algo,
-            backend=config.backend, max_iter=config.max_iter,
-            obj_chunk=config.chunk_size, seed=config.seed,
-            est_iters=config.est_iters, df=df,
-            checkpoint_dir=config.checkpoint_dir,
-            checkpoint_every=config.checkpoint_every,
-            tune=config.tune)
-        n = docs.n_docs
-        # The sharded state's means are consumed by the artifact's index.
-        index = index_from_means_t(state.means_t, params,
-                                   moving=state.moving, donate=True)
-        core_state = KMeansState(
-            index=index,
-            assign=state.assign[:n],
-            rho_self=state.rho_self[:n],
-            rho_self_prev=state.rho_prev[:n],
-            iteration=state.iteration,
-            ub=state.ub[:n],
-        )
+        with obs.fit() as rec:
+            state, history, converged, params = mesh_fit(
+                docs, config.k, config.mesh, algo=config.algo,
+                backend=config.backend, max_iter=config.max_iter,
+                obj_chunk=config.chunk_size, seed=config.seed,
+                est_iters=config.est_iters, df=df,
+                checkpoint_dir=config.checkpoint_dir,
+                checkpoint_every=config.checkpoint_every,
+                tune=config.tune)
+            counts = obs.RowCounts(rec)
+            n = docs.n_docs
+            with obs.span("mesh.finish"):
+                # The sharded state's means are consumed by the artifact's
+                # index.
+                index = index_from_means_t(state.means_t, params,
+                                           moving=state.moving, donate=True)
+                core_state = KMeansState(
+                    index=index,
+                    assign=state.assign[:n],
+                    rho_self=state.rho_self[:n],
+                    rho_self_prev=state.rho_prev[:n],
+                    iteration=state.iteration,
+                    ub=state.ub[:n],
+                )
+                assign = np.asarray(core_state.assign)
+            if history:
+                for key, value in counts.take().items():
+                    history[-1][key] += value
         return LloydResult(
             state=core_state,
-            assign=np.asarray(core_state.assign),
+            assign=assign,
             history=history,
             params=params,
             converged=converged,

@@ -21,13 +21,17 @@ same minimiser; DESIGN.md §2 records the substitution.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from functools import partial
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro import obs
+from repro.compat import shard_map
 from repro.sparse import SparseDocs
 from repro.core.meanindex import StructuralParams, delta_v_bar, mfh_table
 
@@ -42,17 +46,77 @@ class EstGrid:
     chunk: int = 2048        # objects per φ̃3 chunk
 
 
-@partial(jax.jit, static_argnames=("s_min", "grid"))
+def _k_axis(means_t) -> str | None:
+    """The mesh axis the (D, K) means are sharded over along K, when that
+    axis spans more than one device; None for means on one device."""
+    sh = getattr(means_t, "sharding", None)
+    if not isinstance(sh, NamedSharding):
+        return None
+    spec = tuple(sh.spec) + (None, None)
+    axis = spec[1]
+    if isinstance(axis, tuple):
+        axis = axis[0] if len(axis) == 1 else None
+    if spec[0] is not None or axis is None or sh.mesh.shape[axis] < 2:
+        return None
+    return axis
+
+
 def _v_candidates(means_t: jax.Array, s_min: int, grid: EstGrid) -> jax.Array:
-    """v_th candidates from quantiles of the positive tail-region values.
-    One compiled program, so the tail slice and its NaN-masked copy fuse
-    into the quantile instead of each holding device memory."""
-    tail = means_t[s_min:]
-    masked = jnp.where(tail > 0, tail, jnp.nan)   # static shape; zeros ignored
-    qs = jnp.linspace(grid.v_quantile_lo, grid.v_quantile_hi, grid.n_v)
-    cand = jnp.nanquantile(masked, qs)
-    cand = jnp.where(jnp.isnan(cand), 1.0, cand)  # degenerate tail -> vacuous
-    return jnp.maximum(cand, 1e-6)
+    """v_th candidates: ``grid.n_v`` quantiles of the positive tail-region
+    values (rows ``s_min`` on), as ``jnp.nanquantile`` of the tail with its
+    zeros masked out gives them; 1.0 for a tail with no positive value."""
+    axis = _k_axis(means_t)
+    mesh = None if axis is None else means_t.sharding.mesh
+    return _v_candidates_fn(mesh, axis, s_min, grid)(means_t)
+
+
+@functools.lru_cache(maxsize=None)
+def _v_candidates_fn(mesh, axis: str | None, s_min: int, grid: EstGrid):
+    """The program of :func:`_v_candidates`, one per geometry.  It neither
+    sorts the tail nor, for means sharded over ``axis`` along K, gathers it
+    onto one device: each quantile's two order statistics are found by
+    bisection over the float32 bit patterns (positive floats order as
+    their bits), each step counting the positive tail values at or below a
+    probe on every device and summing the counts over ``axis``.  The
+    quantiles are ``nanquantile``'s linear interpolation between those
+    order statistics, with the positive values counted exactly in int32
+    (``nanquantile`` counts them in float32, exact below 2**24)."""
+    total = (lambda x: x) if axis is None else partial(lax.psum,
+                                                       axis_name=axis)
+
+    def tail_quantiles(means_t):
+        tail = means_t[s_min:]
+        pos = tail > 0
+        v = jnp.where(pos, tail, jnp.inf)
+        n = total(jnp.sum(pos, dtype=jnp.int32))
+        qs = jnp.linspace(grid.v_quantile_lo, grid.v_quantile_hi, grid.n_v)
+        at = qs * (n - 1).astype(jnp.float32)
+        lo, hi = jnp.floor(at), jnp.ceil(at)
+        w = at - lo
+        ranks = jnp.clip(jnp.concatenate([lo, hi]).astype(jnp.int32), 0,
+                         jnp.maximum(n - 1, 0))
+
+        def bisect(_, bounds):
+            below, above = bounds            # the order statistic's bits
+            mid = below + (above - below) // 2
+            x = lax.bitcast_convert_type(mid, jnp.float32)
+            count = total(jnp.sum(v[:, :, None] <= x, axis=(0, 1),
+                                  dtype=jnp.int32))
+            ok = count > ranks
+            return jnp.where(ok, below, mid + 1), jnp.where(ok, mid, above)
+
+        inf_bits = jnp.full_like(ranks, 0x7F800000)
+        bits, _ = lax.fori_loop(0, 31, bisect,
+                                (jnp.zeros_like(ranks), inf_bits))
+        x = lax.bitcast_convert_type(bits, jnp.float32)
+        cand = x[:grid.n_v] * (1.0 - w) + x[grid.n_v:] * w
+        cand = jnp.where(n > 0, cand, 1.0)   # degenerate tail -> vacuous
+        return jnp.maximum(cand, 1e-6)
+
+    if axis is None:
+        return jax.jit(tail_quantiles)
+    return jax.jit(shard_map(tail_quantiles, mesh=mesh, in_specs=P(None, axis),
+                             out_specs=P()))
 
 
 @partial(jax.jit, static_argnames=("k",))

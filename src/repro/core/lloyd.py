@@ -319,31 +319,6 @@ def _history_row(r: int, n: int, k: int, mult, cand, changed, obj, nmov,
     }
 
 
-class _RowCounts:
-    """Each history row's share of the open fit's counters (``repro.obs``):
-    what happened since the previous row was taken, so that a fit's rows
-    sum to what happened inside it.  ``compiles``, ``compile_s`` and
-    ``cache_hits`` are XLA compilations, their seconds and persistent-cache
-    hits; ``estparams_s`` is the host time of the ``repro.estparams``
-    span."""
-
-    def __init__(self, rec: obs.FitRecord):
-        self._rec = rec
-        self._mark = self._now()
-
-    def _now(self) -> dict:
-        rec = self._rec
-        return {"compiles": rec.compiles, "compile_s": rec.compile_s,
-                "cache_hits": rec.cache_hits,
-                "estparams_s": rec.span_s.get("estparams", 0.0)}
-
-    def take(self) -> dict:
-        now = self._now()
-        share = {key: now[key] - self._mark[key] for key in now}
-        self._mark = now
-        return share
-
-
 def lloyd_fit(docs: SparseDocs, *, k: int, algo: str = "esicp",
               backend: str = "reference", params="auto",
               batch_size: int = 4096, max_iter: int = 60,
@@ -371,15 +346,15 @@ def lloyd_fit(docs: SparseDocs, *, k: int, algo: str = "esicp",
     Traced in ``repro.lloyd.*`` spans (``repro.obs``): ``prepare``, one
     ``iteration`` per prologue iteration, ``fused``, ``pull`` around every
     device→host sync and ``finish``.  Each history row also holds its share
-    of the fit's counters (:class:`_RowCounts`): the first row holds the
-    set-up's, the first fused row the whole fused call's (0 on the later
-    ones) and the last row the finish's.  ``scan_slot_share`` is the same
+    of the fit's counters (:class:`repro.obs.RowCounts`): the first row
+    holds the set-up's, the first fused row the whole fused call's (0 on
+    the later ones) and the last row the finish's.  ``scan_slot_share`` is the same
     on every row: :func:`scan_slot_share` of the epoch's tiles (engines
     with a plan run no slot scan; there it reads what the reference scan
     would take over their tiles).
     """
     with obs.fit() as rec:
-        counts = _RowCounts(rec)
+        counts = obs.RowCounts(rec)
         est_grid = est_grid or EstGrid()
         est_iters = tuple(est_iters)
         n = docs.n_docs

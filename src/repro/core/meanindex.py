@@ -215,11 +215,13 @@ def mean_value_stats(means_t: jax.Array, t_th: jax.Array):
     return (jnp.sum(means_t, axis=1),)
 
 
+@jax.jit
 def delta_v_bar(means_t: jax.Array, v_grid: jax.Array) -> jax.Array:
     """Δv̄_{s,h} = (1/K) Σ_k relu(v_h − v_{s,k})  — Eq. (39).
 
     Includes absent centroids (v = 0), matching the (K − mf_s)·v_h term.
-    Returns (D, H) float32.
+    Returns (D, H) float32.  Jitted, so each fit reuses one program (an
+    eager ``lax.map`` over a fresh closure is a new program every call).
     """
     def per_h(v_h):
         return jnp.mean(jnp.maximum(v_h - means_t, 0.0), axis=1)
@@ -229,8 +231,10 @@ def delta_v_bar(means_t: jax.Array, v_grid: jax.Array) -> jax.Array:
     return jax.lax.map(per_h, v_grid).T
 
 
+@jax.jit
 def mfh_table(means_t: jax.Array, v_grid: jax.Array) -> jax.Array:
-    """(mfH)_{s,h} for every v_th candidate — (D, H) int32 (Eq. 9)."""
+    """(mfH)_{s,h} for every v_th candidate — (D, H) int32 (Eq. 9).
+    Jitted, as :func:`delta_v_bar` is."""
 
     def per_h(v_h):
         return jnp.sum(means_t >= v_h, axis=1).astype(jnp.int32)

@@ -40,15 +40,18 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import time
 import warnings
 from functools import partial
 from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro import obs
 from repro.compat import shard_map
 
 
@@ -114,7 +117,8 @@ def _step_local(ids, vals, valid, assign, rho_self, rho_prev, ub, means_t,
     d, k_loc = means_t.shape
     k0 = lax.axis_index("model") * k_loc
     xstate = (rho_self >= rho_prev) & (iteration >= 2) & valid
-    index_loc = _local_index(means_t, moving, t_th, v_th)
+    with jax.named_scope("assign"):
+        index_loc = _local_index(means_t, moving, t_th, v_th)
     # Bound groups tier the GLOBAL centroid ids (static geometry; k0 is
     # traced, so the local-column → group map is a traced gather index).
     gsz = ub_group_size(k)
@@ -210,9 +214,10 @@ def _step_local(ids, vals, valid, assign, rho_self, rho_prev, ub, means_t,
                 raise ValueError(algo)
         lbest = jnp.max(masked, axis=1)
         lidx = (jnp.argmax(masked, axis=1) + k0).astype(jnp.int32)
-        best = lax.pmax(lbest, "model")
-        cand = jnp.where(lbest >= best, lidx, k)      # lowest global id wins
-        widx = lax.pmin(cand, "model").astype(jnp.int32)
+        with jax.named_scope("mesh.collective"):
+            best = lax.pmax(lbest, "model")
+            cand = jnp.where(lbest >= best, lidx, k)  # lowest global id wins
+            widx = lax.pmin(cand, "model").astype(jnp.int32)
         improve = (best > crho) & cval
         na = jnp.where(improve, widx, cassign)
         n_surv = jnp.sum(jnp.where(cval[:, None], surv, False),
@@ -237,20 +242,23 @@ def _step_local(ids, vals, valid, assign, rho_self, rho_prev, ub, means_t,
             nb = jnp.where(gcols == na[:, None], -jnp.inf, b)
             gb = jnp.max(jnp.where(gmat[None, :, :], nb[:, :, None],
                                    -jnp.inf), axis=1)         # (C, G)
-            gb = lax.pmax(gb, "model")
+            with jax.named_scope("mesh.collective"):
+                gb = lax.pmax(gb, "model")
             cub_new = jnp.where(ga, gb, cub)
         return na, n_surv, cub_new
 
     resh = lambda a: a.reshape((nc, obj_chunk) + a.shape[1:])
     occ_r = None if occ is None else occ.reshape((nc, gpt) + occ.shape[1:])
     head_r = None if head is None else resh(head)
-    na, n_surv, nub = lax.map(chunk_fn, ((resh(ids), resh(vals), resh(valid),
-                                          resh(assign), resh(rho_self),
-                                          resh(xstate), resh(ub)),
-                                         (occ_r, head_r)))
+    with jax.named_scope("assign"):
+        na, n_surv, nub = lax.map(chunk_fn, ((resh(ids), resh(vals),
+                                              resh(valid), resh(assign),
+                                              resh(rho_self), resh(xstate),
+                                              resh(ub)), (occ_r, head_r)))
     assign_new = na.reshape(n_loc)
     ub_new = nub.reshape((n_loc,) + nub.shape[2:])
-    n_candidates = lax.psum(jnp.sum(n_surv), axes_obj + ("model",))
+    with jax.named_scope("mesh.collective"):
+        n_candidates = lax.psum(jnp.sum(n_surv), axes_obj + ("model",))
 
     # ---------------- update: cluster sums for owned centroids -------------
     # The backend owns the segment sums (reference scatter drops the
@@ -270,20 +278,39 @@ def _step_local(ids, vals, valid, assign, rho_self, rho_prev, ub, means_t,
             head, ci * obj_chunk, obj_chunk, 0)
         return _chunk_plan(o, h)
 
+    # The reference engine's scatter-add runs over the two halves of the
+    # shard's rows, whatever the number of chunks; only a plan, which is
+    # cut per chunk, takes the rows chunk by chunk.  On the TPU XLA adds
+    # into the (K_loc, D) sums through a flat copy of the whole buffer,
+    # once per scatter: a scatter per chunk took 17.5 s of a 19 s step on
+    # one v5e at nyt1m's width (K_loc=2,500, 64 chunks), and one scatter
+    # over all rows needs a second buffer of the sums' size (AOT,
+    # described v5e), a loop over two halves neither.
+    if plan_meta is None and n_loc % 2:
+        raise ValueError(f"{n_loc} rows per shard do not split into two "
+                         f"halves: pad each shard to an even row count")
+    rows = n_loc // 2 if plan_meta is None else obj_chunk
+
     def acc_body(ci, lam):
-        sl = lambda a: lax.dynamic_slice_in_dim(a, ci * obj_chunk, obj_chunk, 0)
+        sl = lambda a: lax.dynamic_slice_in_dim(a, ci * rows, rows, 0)
         cvals = jnp.where(sl(in_range)[:, None], sl(vals), 0.0)
         return bk.accumulate_means(sl(ids), cvals, sl(safe_a),
                                    k=k_loc, dim=d, init=lam,
                                    plan=_upd_plan(ci))
 
-    lam = lax.fori_loop(0, nc, acc_body, jnp.zeros((k_loc, d), jnp.float32))
-    # §Perf variant: compress the cluster-sum all-reduce (the step's dominant
-    # collective) to bf16 — the k-means analogue of gradient compression.
-    # Not bit-exact vs Lloyd; f32 default preserves the acceleration contract.
-    lam = lax.psum(lam.astype(lambda_dtype), axes_obj).astype(jnp.float32)
-    means_new = normalized_means(lam, means_t)
-    means_new_t = means_new.T.astype(means_t.dtype)             # (D, K_loc)
+    with jax.named_scope("update.sums"):
+        lam = lax.fori_loop(0, n_loc // rows, acc_body,
+                            jnp.zeros((k_loc, d), jnp.float32))
+        # §Perf variant: compress the cluster-sum all-reduce (the step's
+        # dominant collective) to bf16 — the k-means analogue of gradient
+        # compression.  Not bit-exact vs Lloyd; f32 default preserves the
+        # acceleration contract.
+        with jax.named_scope("mesh.collective"):
+            lam = lax.psum(lam.astype(lambda_dtype), axes_obj)
+        lam = lam.astype(jnp.float32)
+    with jax.named_scope("update.normalize"):
+        means_new = normalized_means(lam, means_t)
+        means_new_t = means_new.T.astype(means_t.dtype)         # (D, K_loc)
 
     # ---------------- ρ_self refresh (Alg. 6 lines 6–7) --------------------
     def rho_body(ci, out):
@@ -293,8 +320,12 @@ def _step_local(ids, vals, valid, assign, rho_self, rho_prev, ub, means_t,
                          plan=_upd_plan(ci))
         return lax.dynamic_update_slice_in_dim(out, r, ci * obj_chunk, 0)
 
-    rho_new = lax.fori_loop(0, nc, rho_body, jnp.zeros((n_loc,), jnp.float32))
-    rho_new = lax.psum(rho_new, "model")    # exactly one shard contributes
+    with jax.named_scope("update.rho"):
+        rho_new = lax.fori_loop(0, nc, rho_body,
+                                jnp.zeros((n_loc,), jnp.float32))
+        with jax.named_scope("mesh.collective"):
+            # exactly one shard contributes
+            rho_new = lax.psum(rho_new, "model")
 
     # ---------------- exact ICP flags from membership deltas ---------------
     changed = (assign_new != assign) & valid
@@ -303,10 +334,11 @@ def _step_local(ids, vals, valid, assign, rho_self, rho_prev, ub, means_t,
     mv = jnp.zeros((k_loc + 1,), jnp.int32)
     mv = mv.at[safe_a].max(changed.astype(jnp.int32))
     mv = mv.at[old_local].max(changed.astype(jnp.int32))
-    moving_new = lax.psum(mv[:k_loc], axes_obj) > 0
-
-    n_changed = lax.psum(jnp.sum(changed, dtype=jnp.float32), axes_obj)
-    objective = lax.psum(jnp.sum(jnp.where(valid, rho_new, 0.0)), axes_obj)
+    with jax.named_scope("mesh.collective"):
+        moving_new = lax.psum(mv[:k_loc], axes_obj) > 0
+        n_changed = lax.psum(jnp.sum(changed, dtype=jnp.float32), axes_obj)
+        objective = lax.psum(jnp.sum(jnp.where(valid, rho_new, 0.0)),
+                             axes_obj)
 
     # Bound maintenance against the means THIS step just produced: each
     # bound group's worst per-center angular drift (local columns scattered
@@ -316,20 +348,29 @@ def _step_local(ids, vals, valid, assign, rho_self, rho_prev, ub, means_t,
     # group_drift pass.
     dots = jnp.sum(means_new_t * means_t, axis=0)
     d_loc = jnp.arccos(jnp.clip(dots, -1.0, 1.0))             # (K_loc,)
-    delta = lax.pmax(
-        jnp.max(jnp.where(gmat, d_loc[:, None], 0.0), axis=0), "model")
+    with jax.named_scope("mesh.collective"):
+        delta = lax.pmax(
+            jnp.max(jnp.where(gmat, d_loc[:, None], 0.0), axis=0), "model")
     ub_new = drift_loosen(ub_new, delta)
 
     return (means_new_t, assign_new, rho_new, rho_self, ub_new, moving_new,
             n_changed, n_candidates, objective)
 
 
+@functools.lru_cache(maxsize=None)
 def make_step_fn(mesh: Mesh, *, algo: str = "esicp", k: int,
                  obj_chunk: int = 2048, lambda_dtype=jnp.float32,
                  taat_unroll: bool = False, two_phase: bool = False,
                  p_block: int = 1, p_tail: int = 16,
                  backend: str = "reference", plan_meta: PlanMeta | None = None):
-    """Builds the jitted fused assignment+update step for `mesh`.
+    """Builds the jitted fused assignment+update step for `mesh`, once per
+    set of arguments: every fit with the same geometry calls the same
+    executable, named ``jit_mesh_step``.  Its ops carry their phase in their
+    names: ``assign``, ``update.sums``, ``update.normalize``, ``update.rho``,
+    and ``mesh.collective`` on every cross-chip reduction.  The means are
+    not donated: XLA copies an aliased means buffer on entry to the
+    assignment loop that reads it (14.8 GB of temporaries per chip at
+    nyt1m's width, against 5.2 GB undonated).
 
     taat_unroll: dry-run costing mode — unrolls the P-step TAAT scan so
     XLA's cost model counts every multiply (launch/dryrun.py pass B).
@@ -369,7 +410,41 @@ def make_step_fn(mesh: Mesh, *, algo: str = "esicp", k: int,
                 p_block=p_block, p_tail=p_tail, backend=backend,
                 plan_meta=plan_meta),
         mesh=mesh, in_specs=specs_in, out_specs=specs_out)
-    return jax.jit(fn)
+
+    def mesh_step(*args):
+        return fn(*args)
+
+    return jax.jit(mesh_step)
+
+
+def step_collective_bytes(mesh: Mesh, *, algo: str, k: int, dim: int,
+                          n_rows: int, lambda_dtype=jnp.float32) -> int:
+    """Bytes one chip's collectives carry in one step, reckoned from the
+    shapes of what each reduces: its payload where the reduction spans more
+    than one chip, zero where its group is one chip.
+
+    - assignment: per object, the (best, id) pair over 'model' (a pmax and a
+      pmin of 4 bytes each; the bounds modes add the G group bounds), so the
+      exchange grows with N, never with N·K;
+    - the candidate count over every axis; the (K/|model|, D) cluster sums,
+      the moving flags, #changed and the objective over the object axes;
+    - the ρ_self refresh and the per-group drift over 'model'.
+    """
+    from repro.core.update import n_ub_groups
+
+    n_model = mesh.shape["model"]
+    n_obj = int(np.prod([mesh.shape[a] for a in object_axes(mesh)]))
+    n_loc, k_loc, g = n_rows // n_obj, k // n_model, n_ub_groups(k)
+    lam_word = jnp.dtype(lambda_dtype).itemsize
+
+    def over(group: int, nbytes: int) -> int:
+        return nbytes if group > 1 else 0
+
+    per_doc = 8 + (4 * g if algo in ("bounds", "bounds-esicp") else 0)
+    return (over(n_model, n_loc * per_doc)
+            + over(n_obj * n_model, 4)
+            + over(n_obj, k_loc * dim * lam_word + k_loc * 4 + 4 + 4)
+            + over(n_model, n_loc * 4 + g * 4))
 
 
 def build_plan_operands(ids, vals, valid, *, dim: int, obj_chunk: int,
@@ -430,9 +505,7 @@ def dist_init_state(docs, k: int, mesh: Mesh, *, n_rows: int,
     (>= n_docs) sizes the per-document state; rows past ``n_docs`` are
     dead padding and start at the ρ_self = 0 / ub = 0 convention.
     """
-    import numpy as np
-
-    from repro.core.update import n_ub_groups, seed_centroids, seed_rows
+    from repro.core.update import n_ub_groups, seed_rows
     from repro.sparse import SparseDocs
     from repro.sparse.store import DocStore
 
@@ -446,23 +519,38 @@ def dist_init_state(docs, k: int, mesh: Mesh, *, n_rows: int,
     else:
         sel = SparseDocs(ids=docs.ids[pick], vals=docs.vals[pick],
                          nnz=docs.nnz[pick], dim=docs.dim)
-    k_loc = k // n_model
+    means_t = _seed_means_fn(mesh, k // n_model, docs.dim)(
+        *(np.asarray(a) for a in (sel.ids, sel.vals, sel.nnz)))
+    assign, rho_self, rho_prev, ub, moving = _per_doc_fn(
+        mesh, n, n_rows, n_ub_groups(k), k)()
+    return DistKMeansState(means_t=means_t, assign=assign, rho_self=rho_self,
+                           rho_prev=rho_prev, moving=moving,
+                           iteration=jnp.asarray(0, jnp.int32), ub=ub)
+
+
+@functools.lru_cache(maxsize=None)
+def _seed_means_fn(mesh: Mesh, k_loc: int, dim: int):
+    """Jitted once per geometry: each 'model' shard scatters its own K_loc
+    seed rows into its (D, K_loc) slice of the means."""
+    from repro.core.update import seed_centroids
+    from repro.sparse import SparseDocs
 
     def seed_shard(ids, vals, nnz):
         k0 = lax.axis_index("model") * k_loc
         sl = lambda a: lax.dynamic_slice_in_dim(a, k0, k_loc, 0)
-        part = SparseDocs(ids=sl(ids), vals=sl(vals), nnz=sl(nnz),
-                          dim=docs.dim)
+        part = SparseDocs(ids=sl(ids), vals=sl(vals), nnz=sl(nnz), dim=dim)
         return seed_centroids(part, k_loc).T               # (D, K_loc)
 
-    means_t = jax.jit(shard_map(seed_shard, mesh=mesh,
-                                in_specs=(P(), P(), P()),
-                                out_specs=P(None, "model")))(
-        *(np.asarray(a) for a in (sel.ids, sel.vals, sel.nnz)))
+    return jax.jit(shard_map(seed_shard, mesh=mesh, in_specs=(P(), P(), P()),
+                             out_specs=P(None, "model")))
 
+
+@functools.lru_cache(maxsize=None)
+def _per_doc_fn(mesh: Mesh, n: int, n_rows: int, g: int, k: int):
+    """Jitted once per geometry: the per-document state (and the moving
+    flags), created directly in its sharding."""
     axes_obj = object_axes(mesh)
     sh = lambda spec: NamedSharding(mesh, spec)
-    g = n_ub_groups(k)
 
     def per_doc():
         real = jnp.arange(n_rows) < n
@@ -472,13 +560,9 @@ def dist_init_state(docs, k: int, mesh: Mesh, *, n_rows: int,
                 jnp.broadcast_to(ub[:, None], (n_rows, g)),
                 jnp.ones((k,), bool))
 
-    assign, rho_self, rho_prev, ub, moving = jax.jit(
-        per_doc, out_shardings=(sh(P(axes_obj)), sh(P(axes_obj)),
-                                sh(P(axes_obj)), sh(P(axes_obj, None)),
-                                sh(P("model"))))()
-    return DistKMeansState(means_t=means_t, assign=assign, rho_self=rho_self,
-                           rho_prev=rho_prev, moving=moving,
-                           iteration=jnp.asarray(0, jnp.int32), ub=ub)
+    return jax.jit(per_doc, out_shardings=(
+        sh(P(axes_obj)), sh(P(axes_obj)), sh(P(axes_obj)),
+        sh(P(axes_obj, None)), sh(P("model"))))
 
 
 @functools.lru_cache(maxsize=None)
@@ -561,15 +645,22 @@ def mesh_fit(docs, k: int, mesh: Mesh, *, algo: str = "esicp",
     tail padding; rows ``[:docs.n_docs]`` are the real ones), the diagnostic
     history, the convergence flag, and the final StructuralParams.
 
+    Traced in ``repro.mesh.*`` spans (``repro.obs``): ``prepare``
+    (placement, seeding, plan operands, the step's build), one
+    ``iteration`` per Lloyd iteration (arg ``iteration``: the step,
+    EstParams, the pull, a checkpoint) and ``pull`` around each
+    iteration's one device→host sync.  Each history row holds its share of
+    the fit's counters (:class:`repro.obs.RowCounts`; the first row also
+    the set-up's), ``elapsed_s`` (the iteration's host seconds, from the
+    step's call to the end of its pull) and ``collective_bytes``
+    (:func:`step_collective_bytes`).
+
     This is the 'mesh' execution strategy behind
     ``repro.cluster.SphericalKMeans(mesh=...)`` — prefer the estimator,
     which trims padding and wraps the result in a FittedModel.
     """
-    import numpy as np
     from repro.cluster.config import ClusterConfig
-    from repro.core.estparams import estimate_params
-    from repro.core.meanindex import StructuralParams
-    from repro.sparse.store import DocStore
+    from repro.core.backends import resolve_backend
 
     # Front-door validation (the same fail-fast contract as the estimator
     # and resolve_strategy): unknown algo/backend/tune, a K that doesn't
@@ -578,12 +669,87 @@ def mesh_fit(docs, k: int, mesh: Mesh, *, algo: str = "esicp",
                   chunk_size=obj_chunk, mesh=mesh, est_iters=est_iters,
                   checkpoint_dir=checkpoint_dir,
                   checkpoint_every=checkpoint_every, tune=tune).validate()
+    two_phase = step_kw.pop("two_phase", False)
+    if two_phase and resolve_backend(backend).name != "reference":
+        # Fail fast: the rebuild at r == max(est_iters) would otherwise
+        # raise after iterations of completed clustering work.
+        raise ValueError("two_phase is a reference-backend scan variant; "
+                         "use backend='reference' with it")
+
+    with obs.fit() as rec:
+        counts = obs.RowCounts(rec)
+        with obs.span("mesh.prepare"):
+            (store, ids, vals, valid, state, step_fn, plan_ops, params, df,
+             coll_bytes) = _mesh_prepare(docs, k, mesh, algo=algo,
+                                         backend=backend, obj_chunk=obj_chunk,
+                                         seed=seed, df=df, tune=tune,
+                                         step_kw=step_kw)
+        n = docs.n_docs
+        history = []
+        converged = False
+        for r in range(1, max_iter + 1):
+            with obs.span("mesh.iteration", iteration=r):
+                t0 = time.perf_counter()
+                state, diag = dist_assignment_update(
+                    step_fn, state, ids, vals, valid, params.t_th,
+                    params.v_th, plan_ops)
+                if algo == "esicp" and r in est_iters:
+                    # EstParams' first host sync waits for this step on the
+                    # device; waiting here keeps that wait out of its span.
+                    jax.block_until_ready((state.means_t, state.rho_self))
+                    params = _estimate(docs, store, df, state, n=n, k=k)
+                    if two_phase and r == max(est_iters):
+                        step_fn = _two_phase_step(
+                            docs, store, mesh, params, algo=algo, k=k,
+                            obj_chunk=obj_chunk, backend=backend,
+                            step_kw=step_kw)
+                with obs.span("mesh.pull"):
+                    diag, t_th, v_th = jax.device_get(
+                        (diag, params.t_th, params.v_th))
+                history.append({
+                    "iteration": r,
+                    "n_changed": float(diag["n_changed"]),
+                    "cpr": float(diag["n_candidates"]) / (n * k),
+                    "objective": float(diag["objective"]),
+                    "t_th": int(t_th), "v_th": float(v_th),
+                    "elapsed_s": time.perf_counter() - t0,
+                    **counts.take(),
+                    "collective_bytes": coll_bytes})
+                if checkpoint_dir and r % checkpoint_every == 0:
+                    from repro.checkpoint import save_checkpoint
+                    save_checkpoint(checkpoint_dir, {
+                        "means_t": state.means_t, "assign": state.assign,
+                        "rho_self": state.rho_self,
+                        "rho_prev": state.rho_prev,
+                        "moving": state.moving, "iteration": state.iteration,
+                        "ub": state.ub,
+                        "t_th": params.t_th, "v_th": params.v_th}, step=r)
+            if history[-1]["n_changed"] == 0:
+                converged = True
+                break
+        if history:
+            for key, value in counts.take().items():
+                history[-1][key] += value
+    return state, history, converged, params
+
+
+def _mesh_prepare(docs, k: int, mesh: Mesh, *, algo, backend, obj_chunk,
+                  seed, df, tune, step_kw):
+    """Everything a mesh fit builds before its first step: the sharded
+    object arrays, the seeded state, the prepared-plan operands (pallas
+    engine), the step, the trivial starting thresholds, the df table and
+    the step's collective bytes."""
+    from repro.core.backends import resolve_backend
+    from repro.core.meanindex import StructuralParams
+    from repro.sparse.store import DocStore
 
     store = docs if isinstance(docs, DocStore) else None
     n = docs.n_docs
     axes_obj = object_axes(mesh)
     n_obj_shards = int(np.prod([mesh.shape[a] for a in axes_obj]))
-    multiple = n_obj_shards * obj_chunk
+    # Each shard holds whole chunks, and an even number of rows, so that
+    # the reference engine's sums split into two halves (_step_local).
+    multiple = n_obj_shards * obj_chunk * (1 + obj_chunk % 2)
     sh = lambda spec: NamedSharding(mesh, spec)
 
     if store is not None:
@@ -607,15 +773,7 @@ def mesh_fit(docs, k: int, mesh: Mesh, *, algo: str = "esicp",
     # reduction masks on `valid` regardless, so the pad value never leaks
     # into diagnostics.
     state = dist_init_state(docs, k, mesh, seed=seed, n_rows=n + pad)
-    from repro.core.backends import resolve_backend
 
-    two_phase = step_kw.pop("two_phase", False)
-    if two_phase:
-        if resolve_backend(backend).name != "reference":
-            # Fail fast: the rebuild at r == max(est_iters) would otherwise
-            # raise after iterations of completed clustering work.
-            raise ValueError("two_phase is a reference-backend scan variant; "
-                             "use backend='reference' with it")
     # Once-per-fit prepared-plan operands for the kernel backend: the
     # occupancy map + cached head slabs every iteration's step reuses
     # (documents are constant across Lloyd iterations).
@@ -646,67 +804,54 @@ def mesh_fit(docs, k: int, mesh: Mesh, *, algo: str = "esicp",
     # index restructuring at that moment).
     step_fn = make_step_fn(mesh, algo=algo, k=k, obj_chunk=obj_chunk,
                            backend=backend, plan_meta=plan_meta, **step_kw)
-    params = StructuralParams.trivial(docs.dim)
-
+    coll_bytes = step_collective_bytes(
+        mesh, algo=algo, k=k, dim=docs.dim, n_rows=n + pad,
+        lambda_dtype=step_kw.get("lambda_dtype", jnp.float32))
     if df is None:
         df = docs.df            # cached on the corpus (sparse/matrix.py)
+    return (store, ids, vals, valid, state, step_fn, plan_ops,
+            StructuralParams.trivial(docs.dim), df, coll_bytes)
 
-    history = []
-    converged = False
-    for r in range(1, max_iter + 1):
-        state, diag = dist_assignment_update(step_fn, state, ids, vals, valid,
-                                             params.t_th, params.v_th,
-                                             plan_ops)
-        if algo == "esicp" and r in est_iters:
-            if store is not None:
-                # Full-corpus estimate, chunk-streamed (the same path the
-                # streaming strategy uses); ρ rows beyond the store's tail
-                # are the dead-row 0 convention and contribute nothing.
-                from repro.core.estparams import estimate_params_store
 
-                rho_rows = state.rho_self[:n]
-                rho_rows = jnp.pad(rho_rows, (0, store.n_rows - n))
-                params, _ = estimate_params_store(
-                    store, df, state.means_t, rho_rows, k=k)
-            else:
-                params, _ = estimate_params(docs, df, state.means_t,
-                                            state.rho_self[:n], k=k)
-            if two_phase and r == max(est_iters):
-                if store is not None:
-                    t = int(params.t_th)
-                    slots = np.arange(store.pad_width)[None, :]
-                    nt_h = 0
-                    for j in range(store.n_chunks):
-                        cid, _, cnnz = store.host_chunk(j)
-                        tail = (np.asarray(cid) >= t) \
-                            & (slots < np.asarray(cnnz)[:, None])
-                        nt_h = max(nt_h, int(tail.sum(axis=1).max(initial=0)))
-                else:
-                    nt_h = int(jnp.max(jnp.sum(
-                        (docs.ids >= params.t_th) & docs.row_mask(), axis=1)))
-                pb = step_kw.get("p_block", 1)
-                p_tail = max(nt_h + ((-nt_h) % max(pb, 1)), pb)
-                step_fn = make_step_fn(mesh, algo=algo, k=k,
-                                       obj_chunk=obj_chunk, two_phase=True,
-                                       p_tail=p_tail, backend=backend,
-                                       **step_kw)
-        history.append({"iteration": r,
-                        "n_changed": float(diag["n_changed"]),
-                        "cpr": float(diag["n_candidates"]) / (n * k),
-                        "objective": float(diag["objective"]),
-                        "t_th": int(params.t_th), "v_th": float(params.v_th)})
-        if checkpoint_dir and r % checkpoint_every == 0:
-            from repro.checkpoint import save_checkpoint
-            save_checkpoint(checkpoint_dir, {
-                "means_t": state.means_t, "assign": state.assign,
-                "rho_self": state.rho_self, "rho_prev": state.rho_prev,
-                "moving": state.moving, "iteration": state.iteration,
-                "ub": state.ub,
-                "t_th": params.t_th, "v_th": params.v_th}, step=r)
-        if history[-1]["n_changed"] == 0:
-            converged = True
-            break
-    return state, history, converged, params
+def _estimate(docs, store, df, state: DistKMeansState, *, n: int, k: int):
+    """EstParams (paper Alg. 7) against the K-sharded means."""
+    if store is not None:
+        # Full-corpus estimate, chunk-streamed (the same path the
+        # streaming strategy uses); ρ rows beyond the store's tail are the
+        # dead-row 0 convention and contribute nothing.
+        from repro.core.estparams import estimate_params_store
+
+        rho_rows = jnp.pad(state.rho_self[:n], (0, store.n_rows - n))
+        params, _ = estimate_params_store(store, df, state.means_t, rho_rows,
+                                          k=k)
+    else:
+        from repro.core.estparams import estimate_params
+
+        params, _ = estimate_params(docs, df, state.means_t,
+                                    state.rho_self[:n], k=k)
+    return params
+
+
+def _two_phase_step(docs, store, mesh: Mesh, params, *, algo, k, obj_chunk,
+                    backend, step_kw):
+    """The two-phase step, its verify window sized to the longest
+    above-threshold suffix (ntH) once EstParams has fixed t_th."""
+    if store is not None:
+        t = int(params.t_th)
+        slots = np.arange(store.pad_width)[None, :]
+        nt_h = 0
+        for j in range(store.n_chunks):
+            cid, _, cnnz = store.host_chunk(j)
+            tail = (np.asarray(cid) >= t) & (slots < np.asarray(cnnz)[:, None])
+            nt_h = max(nt_h, int(tail.sum(axis=1).max(initial=0)))
+    else:
+        nt_h = int(jnp.max(jnp.sum(
+            (docs.ids >= params.t_th) & docs.row_mask(), axis=1)))
+    pb = step_kw.get("p_block", 1)
+    p_tail = max(nt_h + ((-nt_h) % max(pb, 1)), pb)
+    return make_step_fn(mesh, algo=algo, k=k, obj_chunk=obj_chunk,
+                        two_phase=True, p_tail=p_tail, backend=backend,
+                        **step_kw)
 
 
 def dist_fit(docs, k: int, mesh: Mesh, **kw):

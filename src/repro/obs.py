@@ -11,8 +11,8 @@ A fit (``with obs.fit() as rec:``) is a record open on the current thread.
 It counts what happens while it is open: XLA compilations and their
 seconds, persistent-cache hits (the ``jax.monitoring`` events the JAX
 compiler records), and the host seconds of every span opened in it, by
-name.  The Lloyd driver writes its share of these into each history row.
-Nothing is switched on: the profiler is the only switch.
+name.  The Lloyd and mesh drivers write each history row's share of these
+(:class:`RowCounts`).  Nothing is switched on: the profiler is the only switch.
 """
 from __future__ import annotations
 
@@ -78,6 +78,30 @@ def span(name: str, **args):
         if rec is not None:
             rec.span_s[name] = (rec.span_s.get(name, 0.0)
                                 + time.perf_counter() - t0)
+
+
+class RowCounts:
+    """Each history row's share of the open fit's counters: what happened
+    since the previous row was taken, so that a fit's rows sum to what
+    happened inside it.  ``compiles``, ``compile_s`` and ``cache_hits`` are
+    XLA compilations, their seconds and persistent-cache hits;
+    ``estparams_s`` is the host time of the ``repro.estparams`` span."""
+
+    def __init__(self, rec: FitRecord):
+        self._rec = rec
+        self._mark = self._now()
+
+    def _now(self) -> dict:
+        rec = self._rec
+        return {"compiles": rec.compiles, "compile_s": rec.compile_s,
+                "cache_hits": rec.cache_hits,
+                "estparams_s": rec.span_s.get("estparams", 0.0)}
+
+    def take(self) -> dict:
+        now = self._now()
+        share = {key: now[key] - self._mark[key] for key in now}
+        self._mark = now
+        return share
 
 
 def _on_duration(event: str, duration: float, **_) -> None:

@@ -33,6 +33,51 @@ def test_dist_matches_single_device(corpus_small):
     assert (km.labels_ == ref.labels_).all()
 
 
+def _scatter_adds(jaxpr, scope: str, trips: int = 1, stack: str = "") -> int:
+    """How many scatter-adds under the name scope ``scope`` one run of
+    ``jaxpr`` executes: loops (scans) count times their trip count."""
+    total = 0
+    for eqn in jaxpr.eqns:
+        here = stack + "/" + str(eqn.source_info.name_stack)
+        if eqn.primitive.name == "scatter-add" and scope in here:
+            total += trips
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            n = eqn.params["length"] if eqn.primitive.name == "scan" else 1
+            total += _scatter_adds(sub, scope, trips * n, here)
+    return total
+
+
+@pytest.mark.parametrize("n_docs", [320, 512])
+def test_mesh_sums_scatter_over_two_halves_for_any_chunk_count(n_docs):
+    """The reference engine's cluster sums take two scatters per step,
+    whether a shard holds an odd (320 rows padded to 3 chunks of 128) or
+    an even (4) number of chunks, and the fit keeps the plain labels."""
+    from repro.core.update import n_ub_groups
+    from repro.data import make_corpus, CorpusSpec
+    from repro.distributed.kmeans import make_step_fn
+
+    docs, df, _, _ = make_corpus(CorpusSpec(n_docs=n_docs, vocab=512,
+                                            nt_mean=20, n_topics=8, seed=3))
+    mesh = make_test_mesh((1, 4), ("data", "model"))
+    ref = SphericalKMeans(k=16, algo="mivi", max_iter=10, batch_size=128,
+                          seed=1).fit(docs, df=df)
+    km = SphericalKMeans(k=16, algo="esicp", max_iter=10, chunk_size=128,
+                         mesh=mesh, seed=1).fit(docs, df=df)
+    assert (km.labels_ == ref.labels_).all()
+
+    n, p, d, k = -(-n_docs // 128) * 128, 8, 64, 16
+    step = make_step_fn(mesh, algo="esicp", k=k, obj_chunk=128)
+    args = (jnp.zeros((n, p), jnp.int32), jnp.zeros((n, p), jnp.float32),
+            jnp.ones((n,), bool), jnp.zeros((n,), jnp.int32),
+            jnp.zeros((n,), jnp.float32), jnp.zeros((n,), jnp.float32),
+            jnp.zeros((n, n_ub_groups(k)), jnp.float32),
+            jnp.zeros((d, k), jnp.float32), jnp.ones((k,), bool),
+            jnp.asarray(0, jnp.int32), jnp.asarray(1.0, jnp.float32),
+            jnp.asarray(1, jnp.int32))
+    assert _scatter_adds(jax.make_jaxpr(step)(*args).jaxpr,
+                         "update.sums") == 2
+
+
 def test_dist_multipod_axes(corpus_small):
     docs, df, perm, topics = corpus_small
     mesh3 = make_test_mesh((2, 2, 2), ("pod", "data", "model"))

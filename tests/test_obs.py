@@ -1,11 +1,13 @@
 """The program's own tracing (``repro.obs``): spans in the profiler's trace,
-per-fit counters in the history, and phase names on the fused fit's ops."""
+per-fit counters in the history, and phase names on the compiled ops, for
+the single-host Lloyd fit and the K-sharded mesh fit."""
 import glob
 import re
 
 import jax
 import jax.monitoring
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from repro import obs
@@ -141,5 +143,131 @@ def test_the_fused_fit_compiles_under_its_name_with_phase_scopes(docs):
     op_names = set(re.findall(r'op_name="([^"]+)"', hlo))
     for scope in ("assign", "update.sums", "update.normalize", "update.index",
                   "update.rho", "update.bounds", "lloyd.diag"):
+        assert any(f"/{scope}/" in n or n.endswith(f"/{scope}")
+                   for n in op_names), scope
+
+
+# -- the mesh fit -------------------------------------------------------------
+
+MESH_SPANS = {"repro.fit", "repro.mesh.prepare", "repro.mesh.iteration",
+              "repro.estparams", "repro.mesh.pull", "repro.mesh.finish"}
+
+
+@pytest.fixture(scope="module")
+def mesh_cfg():
+    from repro.launch.mesh import make_test_mesh
+
+    return ClusterConfig(k=8, max_iter=5, seed=0, chunk_size=128,
+                         mesh=make_test_mesh((1, 4), ("data", "model")))
+
+
+def test_a_mesh_fit_puts_its_spans_under_one_fit(docs, mesh_cfg, tmp_path):
+    fit(docs, mesh_cfg)
+    with jax.profiler.trace(str(tmp_path)):
+        model = fit(docs, mesh_cfg)
+    [path] = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    events = _repro_events(path)
+    assert {e[0] for e in events} == MESH_SPANS
+    by = {name: [e for e in events if e[0] == name] for name in MESH_SPANS}
+    [top] = by["repro.fit"]
+    assert all(e[3]["fit"] == top[3]["fit"] for e in events)
+    assert all(_inside(e, top) for e in events)
+    iters = sorted(by["repro.mesh.iteration"], key=lambda e: e[1])
+    assert [e[3]["iteration"] for e in iters] == list(
+        range(1, model.n_iter + 1))
+    assert len(by["repro.mesh.pull"]) == model.n_iter
+    assert all(sum(_inside(p, it) for p in by["repro.mesh.pull"]) == 1
+               for it in iters)
+    assert all(any(_inside(est, it) for it in iters[:2])
+               for est in by["repro.estparams"])
+
+
+def test_each_mesh_row_holds_its_counters_and_a_warm_fit_compiles_nothing(
+        docs, mesh_cfg):
+    fit(docs, mesh_cfg)
+    rows = fit(docs, mesh_cfg).history
+    assert len(rows) > 2
+    for r in rows:
+        assert set(COUNTERS) | {"elapsed_s", "collective_bytes"} <= set(r)
+        assert r["elapsed_s"] > 0
+    assert all(r["estparams_s"] > 0 for r in rows[:2])
+    assert all(r["estparams_s"] == 0 for r in rows[2:])
+    assert all(r["compiles"] == 0 for r in rows)
+
+
+def _collective_bytes_of(jaxpr, sizes: dict, trips: int = 1) -> int:
+    """Bytes the cross-device reductions of a jaxpr carry per device,
+    walking into loops (times their trip count) and calls: each
+    reduction's operands, where its axes span more than one device."""
+    total = 0
+    for eqn in jaxpr.eqns:
+        name = eqn.primitive.name
+        if name in ("psum", "pmax", "pmin", "psum_invariant"):
+            axes = eqn.params["axes"]
+            group = int(np.prod([sizes[a] for a in axes]))
+            if group > 1:
+                total += trips * sum(v.aval.size * v.aval.dtype.itemsize
+                                     for v in eqn.invars)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            n = eqn.params["length"] if name == "scan" else 1
+            total += _collective_bytes_of(sub, sizes, trips * n)
+    return total
+
+
+@pytest.mark.parametrize("algo", ["esicp", "bounds-esicp"])
+def test_collective_bytes_are_the_steps_shape_arithmetic(algo):
+    from repro.core.update import n_ub_groups
+    from repro.distributed.kmeans import make_step_fn, step_collective_bytes
+    from repro.launch.mesh import make_test_mesh
+
+    mesh = make_test_mesh((1, 4), ("data", "model"))
+    n, p, d, k, chunk = 512, 8, 64, 16, 128
+    g = n_ub_groups(k)
+    step = make_step_fn(mesh, algo=algo, k=k, obj_chunk=chunk)
+    args = (jnp.zeros((n, p), jnp.int32), jnp.zeros((n, p), jnp.float32),
+            jnp.ones((n,), bool), jnp.zeros((n,), jnp.int32),
+            jnp.zeros((n,), jnp.float32), jnp.zeros((n,), jnp.float32),
+            jnp.zeros((n, g), jnp.float32), jnp.zeros((d, k), jnp.float32),
+            jnp.ones((k,), bool), jnp.asarray(0, jnp.int32),
+            jnp.asarray(1.0, jnp.float32), jnp.asarray(1, jnp.int32))
+    walked = _collective_bytes_of(jax.make_jaxpr(step)(*args).jaxpr,
+                                  dict(mesh.shape))
+    # (best, id) per row, the group bounds of the bounds modes, the
+    # candidate count, then ρ_self and the group drift; the object-axis
+    # sums are zero bytes on a 'data' axis of one.
+    per_doc = 8 + (4 * g if algo == "bounds-esicp" else 0)
+    expected = n * per_doc + 4 + n * 4 + g * 4
+    assert step_collective_bytes(mesh, algo=algo, k=k, dim=d,
+                                 n_rows=n) == walked == expected
+
+
+def test_a_mesh_fit_is_bit_identical_with_the_profiler_on(docs, mesh_cfg,
+                                                          tmp_path):
+    plain = fit(docs, mesh_cfg)
+    with jax.profiler.trace(str(tmp_path)):
+        traced = fit(docs, mesh_cfg)
+    assert np.array_equal(plain.labels, traced.labels)
+    assert np.array_equal(np.asarray(plain.index.means_t),
+                          np.asarray(traced.index.means_t))
+
+
+def test_the_mesh_step_compiles_under_its_name_with_phase_scopes(mesh_cfg):
+    from repro.core.update import n_ub_groups
+    from repro.distributed.kmeans import make_step_fn
+
+    n, p, d, k = 256, 8, 64, 8
+    step = make_step_fn(mesh_cfg.mesh, algo="esicp", k=k, obj_chunk=128)
+    hlo = step.lower(
+        jnp.zeros((n, p), jnp.int32), jnp.zeros((n, p), jnp.float32),
+        jnp.ones((n,), bool), jnp.zeros((n,), jnp.int32),
+        jnp.zeros((n,), jnp.float32), jnp.zeros((n,), jnp.float32),
+        jnp.zeros((n, n_ub_groups(k)), jnp.float32),
+        jnp.zeros((d, k), jnp.float32), jnp.ones((k,), bool),
+        jnp.asarray(0, jnp.int32), jnp.asarray(1.0, jnp.float32),
+        jnp.asarray(1, jnp.int32)).compile().as_text()
+    assert hlo.startswith("HloModule jit_mesh_step,")
+    op_names = set(re.findall(r'op_name="([^"]+)"', hlo))
+    for scope in ("assign", "update.sums", "update.normalize", "update.rho",
+                  "mesh.collective"):
         assert any(f"/{scope}/" in n or n.endswith(f"/{scope}")
                    for n in op_names), scope

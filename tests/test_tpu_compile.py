@@ -232,3 +232,24 @@ def test_mesh_step_pallas_compiles_for_2x2_v5e(topo, monkeypatch):
     monkeypatch.setattr(ops, "default_interpret", lambda: False)
     compiled = _compile_mesh_step(topo, "pallas")
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_estparams_quantiles_stay_sharded_on_2x2_v5e(topo):
+    """EstParams' v_th candidates over the K-sharded means at nyt1m's
+    width, K=10,000 over four chips: no chip gathers the (0.2·D, K) tail
+    (3.96 GB whole; the sort over it needed 7.97 GB of temporaries per
+    chip).  Each chip keeps its own 0.99 GB slice of the positive tail
+    values beside its 4.96 GB of means."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as Ps
+
+    from repro.core.estparams import EstGrid, _v_candidates_fn
+
+    mesh = Mesh(np.asarray(topo.devices).reshape(1, 4), ("data", "model"))
+    means = jax.ShapeDtypeStruct((NYT_D, NYT_K), jnp.float32,
+                                 sharding=NamedSharding(mesh,
+                                                        Ps(None, "model")))
+    compiled = _v_candidates_fn(mesh, "model", int(0.8 * NYT_D),
+                                EstGrid()).lower(means).compile()
+    assert "all-gather" not in compiled.as_text()
+    assert _bytes(compiled) <= 6.5e9
