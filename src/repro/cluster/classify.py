@@ -77,7 +77,7 @@ def _routed_fused(backend: str, ids, vals, nnz, dim: int, coarse_index,
     Exactness: the scan accumulates ``vals[:, p] * means_ext[ids[:, p],
     col]`` in ascending-p order — element-for-element the same float32
     additions, in the same order, as the reference flat TAAT scan
-    (``core.backends.reference_scan`` at p_block=1) performs for those
+    (``core.backends.reference_scan``, at any fold) performs for those
     columns.  When the routed candidate set contains the true argmax (always
     at n_probe = K_c; measured as recall@1 below it), the winning similarity
     is therefore *bitwise* equal to the flat path's.

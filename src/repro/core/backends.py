@@ -136,7 +136,7 @@ class Backend(Protocol):
     def accumulate(self, docs: SparseDocs, index: MeanIndex, xstate: jax.Array,
                    *, mode: str, v_ta: jax.Array | None = None,
                    diag: bool = True, unroll: bool | int = False,
-                   p_block: int = 1, plan=None,
+                   p_block: int | None = None, plan=None,
                    with_counts: bool = False) -> dict: ...
 
     def es_filter(self, rho12: jax.Array, y: jax.Array, rho_self: jax.Array,
@@ -169,34 +169,55 @@ def _pad_p(ids, vals, pb: int):
     return ids, vals
 
 
+SCAN_FOLD = 3
+
+
+def scan_fold(p: int) -> int:
+    """Slots :func:`reference_scan` folds into each rewrite of its (B, K)
+    carries when the caller names none: ``SCAN_FOLD``, but never more than
+    the tile's ``p`` slots.  Up to three slots XLA keeps the carries'
+    updates and the Mult count in one fusion on a TPU v5e; at four the
+    count, and from eight each carry, becomes a fusion of its own that reads
+    every gathered block again (DESIGN.md, "The fold")."""
+    return max(min(SCAN_FOLD, p), 1)
+
+
 def reference_scan(docs: SparseDocs, index: MeanIndex, xstate, *, mode: str,
                    v_ta: jax.Array | None = None, diag: bool = True,
-                   unroll: bool | int = False, p_block: int = 1,
+                   unroll: bool | int = False, p_block: int | None = None,
                    with_counts: bool = False):
     """One fused TAAT pass — the paper's MIVI loop order (Alg. 1 lines 1–5).
 
-    On TPU each scan step is one (B,)-gather of a posting row ξ_s block plus
-    a rank-1 multiply-add on the (B, K) accumulator: no data-dependent
+    On TPU each slot is one (B,)-gather of a posting row ξ_s block plus a
+    rank-1 multiply-add on the (B, K) accumulators: no data-dependent
     branches, shared thresholds as masks.
 
     ``sims`` is always the full exact similarity (reference semantics); the
     CPU algorithm would only compute it for survivors — that cost is what the
     verify-mult term in the caller accounts for.
 
-    Perf knobs (§Perf; the distributed step and the dry-run coster thread
-    them through):
+    The fold: each loop step gathers ``p_block`` consecutive slots and adds
+    them to the carries one slot after another, so a document's slots are
+    added 0…nnz−1, one slot per add, whatever the fold; only the number of
+    times the (B, K) carries are written back changes (once per fold, not
+    once per slot).  ``p_block=None`` takes :func:`scan_fold` of the tile's
+    P.  A masked carry multiplies the slot's value by the masked row, so
+    every carry takes one multiply and one add per slot.  The fold keeps
+    the order of operations, so results do not depend on it wherever the
+    compiler does not contract a multiply and an add into one rounding (on
+    the CPU the float carries were seen to differ by at most 1 ulp;
+    ``mult`` and ``counts`` are exact).
+
+    Other knobs (the distributed step and the dry-run coster thread them
+    through):
       diag=False  — skip the Mult count (``mult`` is returned as 0);
-      p_block>1   — gather ``p_block`` posting rows per scan step and fold
-                    them before touching the (B, K) accumulators: accumulator
-                    read/write traffic drops ~p_block× at unchanged gather
-                    traffic;
       unroll      — a static scan over all P slots, unrolled (dry-run
                     exact-FLOPs costing).
 
     Without ``unroll`` the loop runs only to the tile's live width,
-    ``max(nnz)`` rounded up to a ``p_block`` multiple: every slot past it
-    is dead in every row, and a dead slot adds exact zeros to every carry.
-    The result is bit-identical to the scan over all P slots.
+    ``max(nnz)`` rounded up to a whole fold: every slot past it is dead in
+    every row, and a dead slot adds exact zeros to every carry.  The result
+    is bit-identical to the scan over all P slots.
     """
     b, p = docs.ids.shape
     k = index.k
@@ -205,18 +226,17 @@ def reference_scan(docs: SparseDocs, index: MeanIndex, xstate, *, mode: str,
     means_t = index.means_t
     col_ok = col_ok_mask(index, xstate)      # (B, K) — ICP lane mask
     f32 = jnp.float32
-    pb = max(int(p_block), 1)
+    pb = scan_fold(p) if p_block is None else max(int(p_block), 1)
     assert not with_counts or diag, "with_counts requires diag=True"
 
-    def body(carry, xs):
-        idp, vp = xs                          # (pb, B), (pb, B)
-        rows = means_t[idp]                   # (pb, B, K) posting block
-        contrib = vp[..., None] * rows
-        sims = carry["sims"] + jnp.sum(contrib, 0)
-        out = {"sims": sims, "mult": carry["mult"]}
-        live = (vp != 0.0)[..., None]         # (pb, B, 1)
+    def slot(carry, idp, vp):
+        """One slot's adds: ids and values (B,)."""
+        rows = means_t[idp]                   # (B, K) posting rows
+        contrib = vp[:, None] * rows
+        out = dict(carry, sims=carry["sims"] + contrib)
+        live = (vp != 0.0)[:, None]           # (B, 1)
         if diag:
-            nz = (rows > 0) & col_ok[None] & live
+            nz = (rows > 0) & col_ok & live
             # Raw visited pairs (no ICP mask) — the per-(B, K) twin the
             # Pallas diag accumulator produces; ``mult`` keeps col_ok.
             nzr = (rows > 0) & live
@@ -224,43 +244,39 @@ def reference_scan(docs: SparseDocs, index: MeanIndex, xstate, *, mode: str,
             if diag:
                 out["mult"] = carry["mult"] + jnp.sum(nz, dtype=f32)
                 if with_counts:
-                    out["counts"] = carry["counts"] + jnp.sum(nzr, 0, dtype=f32)
-        elif mode == "esicp":
-            tail = (idp >= t_th)[..., None]   # (pb, B, 1)
-            hi = rows >= v_th
+                    out["counts"] = carry["counts"] + nzr.astype(f32)
+        elif mode in ("esicp", "ta"):
+            tail = (idp >= t_th)[:, None]     # (B, 1)
+            # TA: a per-object threshold (Eq. 16).
+            hi = rows >= (v_th if mode == "esicp" else v_ta[:, None])
             exact_mask = jnp.where(tail, hi, True)
-            out["rho12"] = carry["rho12"] + jnp.sum(
-                jnp.where(exact_mask, contrib, 0.0), 0)
-            out["y"] = carry["y"] + jnp.sum(
-                jnp.where(tail & ~hi, vp[..., None], 0.0), 0)
-            if diag:
-                out["mult"] = carry["mult"] + jnp.sum(nz & exact_mask, dtype=f32)
-                if with_counts:
-                    out["counts"] = carry["counts"] + jnp.sum(
-                        nzr & exact_mask, 0, dtype=f32)
-        elif mode == "ta":
-            tail = (idp >= t_th)[..., None]
-            hi = rows >= v_ta[None, :, None]  # per-object threshold (Eq. 16)
-            exact_mask = jnp.where(tail, hi, True)
-            out["rho12"] = carry["rho12"] + jnp.sum(
-                jnp.where(exact_mask, contrib, 0.0), 0)
-            out["y"] = carry["y"] + jnp.sum(
-                jnp.where(tail & ~hi, vp[..., None], 0.0), 0)
+            out["rho12"] = carry["rho12"] + vp[:, None] * jnp.where(
+                exact_mask, rows, 0.0)
+            out["y"] = carry["y"] + jnp.where(tail & ~hi, vp[:, None], 0.0)
             # TA walks each sorted posting until v < v_ta: visits hi entries
             # plus one terminator comparison; mults are the hi entries.
             if diag:
-                out["mult"] = carry["mult"] + jnp.sum(nz & exact_mask, dtype=f32)
+                out["mult"] = carry["mult"] + jnp.sum(nz & exact_mask,
+                                                      dtype=f32)
+                if with_counts:
+                    out["counts"] = carry["counts"] + (
+                        nzr & exact_mask).astype(f32)
         elif mode == "cs":
-            tail = (idp >= t_th)[..., None]
-            out["rho1"] = carry["rho1"] + jnp.sum(
-                jnp.where(tail, 0.0, contrib), 0)
-            out["sq"] = carry["sq"] + jnp.sum(
-                jnp.where(tail & live, rows * rows, 0.0), 0)
+            tail = (idp >= t_th)[:, None]
+            out["rho1"] = carry["rho1"] + vp[:, None] * jnp.where(
+                tail, 0.0, rows)
+            out["sq"] = carry["sq"] + rows * jnp.where(tail & live, rows, 0.0)
             if diag:
                 out["mult"] = carry["mult"] + jnp.sum(nz, dtype=f32)
         else:
             raise ValueError(mode)
-        return out, None
+        return out
+
+    def body(carry, xs):
+        idp, vp = xs                          # (pb, B), (pb, B)
+        for j in range(pb):                   # slot order, one add each
+            carry = slot(carry, idp[j], vp[j])
+        return carry, None
 
     carry = {"sims": jnp.zeros((b, k), f32), "mult": jnp.zeros((), f32)}
     if with_counts:
@@ -272,8 +288,7 @@ def reference_scan(docs: SparseDocs, index: MeanIndex, xstate, *, mode: str,
     elif mode == "cs":
         carry["rho1"] = jnp.zeros((b, k), f32)
         carry["sq"] = jnp.zeros((b, k), f32)
-    ids, vals = (docs.ids, docs.vals) if pb == 1 else _pad_p(docs.ids,
-                                                             docs.vals, pb)
+    ids, vals = _pad_p(docs.ids, docs.vals, pb)
     pp = ids.shape[1]
     xs = (ids.T.reshape(pp // pb, pb, b), vals.T.reshape(pp // pb, pb, b))
     if unroll:
@@ -367,7 +382,7 @@ class ReferenceBackend:
         return None
 
     def accumulate(self, docs, index, xstate, *, mode, v_ta=None, diag=True,
-                   unroll=False, p_block=1, plan=None, with_counts=False):
+                   unroll=False, p_block=None, plan=None, with_counts=False):
         return reference_scan(docs, index, xstate, mode=mode, v_ta=v_ta,
                               diag=diag, unroll=unroll, p_block=p_block,
                               with_counts=with_counts)
@@ -449,7 +464,7 @@ class PallasBackend:
                             tuned=tuned)
 
     def accumulate(self, docs, index, xstate, *, mode, v_ta=None, diag=True,
-                   unroll=False, p_block=1, plan=None, with_counts=False):
+                   unroll=False, p_block=None, plan=None, with_counts=False):
         # unroll / p_block are reference-scan tiling knobs; the kernels tile
         # via their own block specs, so both are accepted and ignored here.
         from repro.kernels import ops
@@ -584,7 +599,7 @@ class XlaBlockedBackend:
                             head_bytes=head_bytes, tuned=tuned)
 
     def accumulate(self, docs, index, xstate, *, mode, v_ta=None, diag=True,
-                   unroll=False, p_block=1, plan=None, with_counts=False):
+                   unroll=False, p_block=None, plan=None, with_counts=False):
         # unroll / p_block are reference-scan tiling knobs; the XLA ops
         # chunk the posting axis themselves, so both are accepted + ignored.
         from repro.kernels import xla_blocked as xb

@@ -37,7 +37,7 @@ from jax import lax
 
 from repro import obs
 from repro.sparse import SparseDocs, pad_rows
-from repro.core.backends import live_steps, resolve_backend
+from repro.core.backends import live_steps, resolve_backend, scan_fold
 from repro.core.meanindex import (StructuralParams, build_mean_index,
                                   index_from_means_t, normalized_means)
 from repro.core.assignment import assign_batch
@@ -108,15 +108,16 @@ def length_order(docs: SparseDocs) -> TileOrder:
                      perm=perm, inv=inv)
 
 
-@partial(jax.jit, static_argnames=("p", "bs"))
-def scan_slot_share(nnz: jax.Array, p: int, bs: int) -> jax.Array:
-    """Slot steps of one assignment epoch over ``bs``-row tiles of rows of
-    these ``nnz`` (in the order the epoch scans them), as a share of the
-    tiles × ``p`` steps of a scan over every slot."""
+@partial(jax.jit, static_argnames=("p", "bs", "fold"))
+def scan_slot_share(nnz: jax.Array, p: int, bs: int, fold: int) -> jax.Array:
+    """Slots one assignment epoch gathers over ``bs``-row tiles of rows of
+    these ``nnz`` (in the order the epoch scans them), ``fold`` slots per
+    step, as a share of the tiles × ``p`` slots of a scan over every slot:
+    each tile's steps run to its longest row rounded up to a whole fold."""
     nb = nnz.shape[0] // bs
-    steps = jnp.sum(jax.vmap(lambda t: live_steps(t, p))(
+    steps = jnp.sum(jax.vmap(lambda t: live_steps(t, p, fold))(
         nnz.reshape(nb, bs)))
-    return steps / (nb * p)
+    return steps * fold / (nb * p)
 
 
 @partial(jax.jit, static_argnames=("algo", "backend", "bs"))
@@ -348,10 +349,12 @@ def lloyd_fit(docs: SparseDocs, *, k: int, algo: str = "esicp",
     device→host sync and ``finish``.  Each history row also holds its share
     of the fit's counters (:class:`repro.obs.RowCounts`): the first row
     holds the set-up's, the first fused row the whole fused call's (0 on
-    the later ones) and the last row the finish's.  ``scan_slot_share`` is the same
-    on every row: :func:`scan_slot_share` of the epoch's tiles (engines
-    with a plan run no slot scan; there it reads what the reference scan
-    would take over their tiles).
+    the later ones) and the last row the finish's.  ``scan_fold`` and
+    ``scan_slot_share`` are the same on every row: the reference scan's fold
+    for the epoch's tile width (:func:`~repro.core.backends.scan_fold`) and
+    :func:`scan_slot_share` of the epoch's tiles at that fold (engines with
+    a plan run no slot scan; there they read what the reference scan would
+    take over their tiles).
     """
     with obs.fit() as rec:
         counts = obs.RowCounts(rec)
@@ -383,9 +386,10 @@ def lloyd_fit(docs: SparseDocs, *, k: int, algo: str = "esicp",
             # A plan is laid out in the corpus's row order and is handed to
             # the update as well, so engines with one keep that order.
             order = length_order(pdocs) if plan is None else None
+            fold = scan_fold(pdocs.pad_width)
             slot_share = scan_slot_share(
                 (pdocs if order is None else order.docs).nnz,
-                p=pdocs.pad_width, bs=bs)
+                p=pdocs.pad_width, bs=bs, fold=fold)
             if n_pad != n:
                 pad = n_pad - n
                 # Dead rows carry ρ_self = 0 — exactly the value every update
@@ -441,7 +445,8 @@ def lloyd_fit(docs: SparseDocs, *, k: int, algo: str = "esicp",
                      state.index.params.v_th, slot_share))
                 history.append(_history_row(
                     r, n, k, *diag[:-1], time.perf_counter() - t0,
-                    {"scan_slot_share": float(diag[-1]), **counts.take()}))
+                    {"scan_slot_share": float(diag[-1]), "scan_fold": fold,
+                     **counts.take()}))
             if history[-1]["n_changed"] == 0:
                 converged = True
                 break
@@ -469,7 +474,7 @@ def lloyd_fit(docs: SparseDocs, *, k: int, algo: str = "esicp",
                     ring_h["cand"][i], ring_h["changed"][i],
                     ring_h["objective"][i], ring_h["n_moving"][i],
                     ring_h["t_th"][i], ring_h["v_th"][i], per_iter,
-                    {"scan_slot_share": float(share),
+                    {"scan_slot_share": float(share), "scan_fold": fold,
                      **(fused_counts if i == 0
                         else dict.fromkeys(fused_counts, 0))}))
             converged = steps > 0 and int(ring_h["changed"][steps - 1]) == 0

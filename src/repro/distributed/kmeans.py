@@ -104,7 +104,7 @@ def _step_local(ids, vals, valid, assign, rho_self, rho_prev, ub, means_t,
                 moving, t_th, v_th, iteration, *plan_args, algo: str, axes_obj,
                 k: int, obj_chunk: int, lambda_dtype=jnp.float32,
                 taat_unroll: bool = False, two_phase: bool = False,
-                p_block: int = 1, p_tail: int = 16,
+                p_block: int | None = None, p_tail: int = 16,
                 backend: str = "reference", plan_meta=None):
     from repro.core.assignment import SKETCH_MARGIN_BETA, _region3_bound
     from repro.core.backends import BACKENDS, gather_verify_scan
@@ -161,7 +161,7 @@ def _step_local(ids, vals, valid, assign, rho_self, rho_prev, ub, means_t,
         if two_phase and algo == "esicp":
             masked, surv = gather_verify_scan(
                 cids, cvals, cnnz, means_t, t_th, v_th, crho, col_ok,
-                unroll=taat_unroll, p_block=p_block, p_tail=p_tail)
+                unroll=taat_unroll, p_block=p_block or 1, p_tail=p_tail)
         else:
             # The shared backend protocol on the local tile: the reference
             # TAAT scan or the pallas kernels, exactly as the single-host
@@ -361,7 +361,7 @@ def _step_local(ids, vals, valid, assign, rho_self, rho_prev, ub, means_t,
 def make_step_fn(mesh: Mesh, *, algo: str = "esicp", k: int,
                  obj_chunk: int = 2048, lambda_dtype=jnp.float32,
                  taat_unroll: bool = False, two_phase: bool = False,
-                 p_block: int = 1, p_tail: int = 16,
+                 p_block: int | None = None, p_tail: int = 16,
                  backend: str = "reference", plan_meta: PlanMeta | None = None):
     """Builds the jitted fused assignment+update step for `mesh`, once per
     set of arguments: every fit with the same geometry calls the same
@@ -374,6 +374,9 @@ def make_step_fn(mesh: Mesh, *, algo: str = "esicp", k: int,
 
     taat_unroll: dry-run costing mode — unrolls the P-step TAAT scan so
     XLA's cost model counts every multiply (launch/dryrun.py pass B).
+    p_block: the reference scan's fold; None (the default) takes
+    ``core.backends.scan_fold`` of the chunk's shape.  Only the dry-run
+    coster pins one.
     backend: 'reference' (TAAT scan) | 'pallas' (kernels on the local tile)
     | 'auto' — see core/backends.py for selection semantics.
     plan_meta: when set, the step takes the prepared-plan operands (the
@@ -847,7 +850,7 @@ def _two_phase_step(docs, store, mesh: Mesh, params, *, algo, k, obj_chunk,
     else:
         nt_h = int(jnp.max(jnp.sum(
             (docs.ids >= params.t_th) & docs.row_mask(), axis=1)))
-    pb = step_kw.get("p_block", 1)
+    pb = step_kw.get("p_block") or 1
     p_tail = max(nt_h + ((-nt_h) % max(pb, 1)), pb)
     return make_step_fn(mesh, algo=algo, k=k, obj_chunk=obj_chunk,
                         two_phase=True, p_tail=p_tail, backend=backend,

@@ -214,25 +214,29 @@ def test_fit_is_bit_identical_to_the_row_order_full_scan(varied, algo,
     assert tiles[3] == rows[3]
 
 
-def _share(nnz, bs, p):
+def _share(nnz, bs, p, fold):
     tiles = np.asarray(nnz).reshape(-1, bs)
-    return tiles.max(axis=1).sum() / (tiles.shape[0] * p)
+    slots = -(-tiles.max(axis=1) // fold) * fold
+    return slots.sum() / (tiles.shape[0] * p)
 
 
 @pytest.mark.parametrize("backend", ["reference", "xla_blocked"])
 def test_scan_slot_share_is_the_epochs_slot_steps(varied, backend):
-    """Σ per-tile longest document / (tiles × P) over the tiles as the epoch
-    scans them: length-ordered on the reference engine, row order on an
-    engine with a plan.  The length order is the smaller share."""
+    """Σ per-tile longest document, rounded up to whole folds, / (tiles × P)
+    over the tiles as the epoch scans them: length-ordered on the reference
+    engine, row order on an engine with a plan.  The length order is the
+    smaller share."""
     docs, df = varied
     bs = 100
     nnz = np.asarray(pad_rows(docs, bs).nnz)
     p = docs.pad_width
+    fold = backends.scan_fold(p)
     res = lloyd.lloyd_fit(docs, k=8, batch_size=bs, max_iter=3, seed=3,
                           df=df, backend=backend)
     expect = _share(np.sort(nnz, kind="stable") if backend == "reference"
-                    else nnz, bs, p)
-    assert _share(np.sort(nnz), bs, p) < _share(nnz, bs, p)
+                    else nnz, bs, p, fold)
+    assert _share(np.sort(nnz), bs, p, fold) < _share(nnz, bs, p, fold)
     shares = [h["scan_slot_share"] for h in res.history]
     assert len(shares) == res.n_iter >= 3
     np.testing.assert_allclose(shares, expect, rtol=1e-6)
+    assert [h["scan_fold"] for h in res.history] == [fold] * res.n_iter
